@@ -42,8 +42,8 @@ def _sweep_page_size(quick: bool) -> list:
     for ps in ([16, 64, 256] if quick else [8, 16, 32, 64, 128, 256, 512]):
         pages_per_seq = total_kv // ps
         pool_pages = b * pages_per_seq
-        kp = jnp.asarray(rng.normal(size=(pool_pages, ps, kvh, d)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(pool_pages, ps, kvh, d)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(pool_pages, kvh, ps, d)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(pool_pages, kvh, ps, d)), jnp.float32)
         table = jnp.asarray(
             rng.permutation(pool_pages).reshape(b, pages_per_seq), jnp.int32)
         fn = jax.jit(lambda q, kp, vp, t, l: paged_attention(q, kp, vp, t, l,

@@ -248,8 +248,8 @@ class UMapConfig:
 
     # --- sharded concurrency (DESIGN.md §12) --------------------------------
     # Page metadata (table + slot free lists + eviction state) is striped
-    # into `shards` independent lock domains keyed by hash(PageKey), so
-    # concurrent faults on different pages never contend.  0 = auto
+    # into `shards` independent lock domains (PagingService._shard_index),
+    # so concurrent faults on different pages rarely contend.  0 = auto
     # (min(16, 2 * num_fillers)); the service additionally clamps to the
     # slot count so every shard owns at least one buffer slot.  mmap_compat
     # forces a single shard (the kernel's mmap_sem serialization).

@@ -8,8 +8,9 @@ documented in DESIGN.md §12):
     it is woken only after the page is atomically installed — UFFDIO_COPY
     semantics).
   * Page metadata is striped into N **shards** (``config.shards`` /
-    ``UMAP_SHARDS``, default ``min(16, 2*fillers)``), keyed by
-    ``hash((region_id, page_no)) % N``.  Each shard owns its own lock +
+    ``UMAP_SHARDS``, default ``min(16, 2*fillers)``): each aligned block
+    of N adjacent pages covers every shard once, in a rotation hashed from
+    ``(region_id, block)`` (``_shard_index``).  Each shard owns its own lock +
     condition, page table, eviction-policy instance, buffer-slot free list,
     and stat counters — concurrent faults on *different* pages contend only
     when they hash to the same stripe.  The seed design's single global
@@ -353,10 +354,25 @@ class PagingService:
     # ----------------------------------------------------------- sharding
 
     def _shard_index(self, key: PageKey) -> int:
-        return hash(key) % len(self.shards)
+        """Stripe of ``key``: each aligned block of N adjacent pages covers
+        all N stripes once, in a rotation hashed from the block.
+
+        Slot free lists are stripe-private, so a run of pages can only be
+        leased if no stripe is asked for more pages than it owns slots.  A
+        plain ``hash(key) % N`` can put 5 pages of a 28-page run on one
+        4-slot stripe, and such a ``lease_run`` aborts and retries forever.
+        Here a run of k pages takes at most ``ceil((k - 1) / N) + 1`` pages
+        of any stripe, which fits the ``num_slots // 2`` run cap whenever
+        every stripe owns >= 4 slots (``UMapConfig.effective_shards``).
+        The hashed rotation keeps a stride of N pages spread over the
+        stripes, as the plain hash did.
+        """
+        rid, pno = key
+        n = len(self.shards)
+        return (pno + hash((rid, pno // n))) % n
 
     def _shard_of(self, key: PageKey) -> _Shard:
-        return self.shards[hash(key) % len(self.shards)]
+        return self.shards[self._shard_index(key)]
 
     @contextlib.contextmanager
     def _locked(self, shard: _Shard):
@@ -607,8 +623,9 @@ class PagingService:
     # --------------------------------------------------------- fault path
 
     def request_fills(self, region: "UMapRegion", page_nos: List[int],
-                      demand: bool = True) -> None:
-        """Post fill work for absent pages (no pinning, no waiting).
+                      demand: bool = True) -> List[PageEntry]:
+        """Post fill work for absent pages (no pinning, no waiting); returns
+        the entries posted for ``page_nos`` (readahead excluded).
 
         Issuing all fills for a multi-page request up front keeps the filler
         pool busy (I/O overlap); the caller then pins/copies one page at a
@@ -623,6 +640,7 @@ class PagingService:
         self._dispatch_fills(region, to_fill + ra_fill)
         if demand and to_fill:
             self._observe_faults(region, [e.key[1] for e in to_fill])
+        return to_fill
 
     def _insert_absent(self, region: "UMapRegion", page_nos: List[int],
                        demand: bool) -> List[PageEntry]:
@@ -939,8 +957,8 @@ class PagingService:
         pages = list(range(first_page, first_page + npages))
         attempt = 0
         while True:
-            if self.config.zero_copy_leases:
-                self.request_fills(region, pages)  # I/O overlap across the run
+            posted = (self.request_fills(region, pages)  # I/O overlap
+                      if self.config.zero_copy_leases else [])
             deadline = time.monotonic() + self._LEASE_RUN_ATTEMPT_S
             leases: List[PageLease] = []
             try:
@@ -951,6 +969,17 @@ class PagingService:
                     if ls is None:
                         break
                     leases.append(ls)
+                # The run waits on every fill it posted, so a failed one
+                # raises here as it would for a thread blocked on that page
+                # (§14.4), even when the page has since been re-faulted:
+                # which of them the grant loop happened to be waiting on
+                # must not decide whether the caller sees the error.
+                failed = next((e for e in posted if e.error is not None), None)
+                if failed is not None:
+                    raise IOError(
+                        f"fill of page {failed.key[1]} in region "
+                        f"{region.name or region.region_id} failed: "
+                        f"{failed.error}") from failed.error
             except BaseException:
                 for ls in leases:
                     ls.abandon()               # never handed out: no dirty mark
@@ -1035,7 +1064,9 @@ class PagingService:
         """Swap the eviction policy at runtime (adaptive engine / app call).
 
         Each shard gets a fresh policy instance that adopts that shard's
-        currently-resident pages; recency history is intentionally dropped
+        resident pages, those mid-write-back included (a CLEANING page left
+        out would return to PRESENT untracked, and its slot could never be
+        reclaimed); recency history is intentionally dropped
         (the swap happens because the access pattern changed — see
         ``EvictionPolicy.adopt``).  Shards are swapped one at a time under
         their own locks (never two shard locks at once); the momentary
@@ -1048,7 +1079,7 @@ class PagingService:
             for shard in self.shards:
                 with self._locked(shard):
                     new_policy = make_policy(name)
-                    new_policy.adopt(shard.table.resident_keys())
+                    new_policy.adopt(shard.table.policy_keys())
                     shard.policy = new_policy
 
     def pattern_snapshot(self, region_id: int) -> Optional[dict]:
@@ -1444,9 +1475,9 @@ class PagingService:
         granule = max(1, self.config.max_batch_pages)
         nq = len(self._fill_qs)
         rid = region.region_id
-        # The 3-tuple salt keeps deque routing decorrelated from metadata
-        # sharding (hash((rid, pno)) % N): with num_fillers == shards an
-        # unsalted route would statically bind each filler to one stripe.
+        # The salted hash keeps deque routing decorrelated from metadata
+        # sharding (``_shard_index``), so no filler is statically bound to
+        # one stripe.
         by_route: Dict[int, List[_FillWork]] = {}
         for entry in entries:
             route = hash((rid, entry.key[1] // granule, "route")) % nq

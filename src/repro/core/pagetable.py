@@ -128,6 +128,12 @@ class PageTable:
         return [k for k, e in list(self._entries.items())
                 if e.state is PageState.PRESENT]
 
+    def policy_keys(self):
+        """Keys an eviction policy tracks: PRESENT pages and CLEANING ones,
+        which return to PRESENT when their write-back lands."""
+        return [k for k, e in list(self._entries.items())
+                if e.state in (PageState.PRESENT, PageState.CLEANING)]
+
     def evictable(self, entry: PageEntry) -> bool:
         return entry.state is PageState.PRESENT and entry.pins == 0
 

@@ -2,15 +2,13 @@
 
 from __future__ import annotations
 
-import jax
-
+from .. import resolve_impl
 from .kernel import page_gather as _gather, page_scatter as _scatter
 from .ref import page_gather_ref, page_scatter_ref
 
 
 def page_gather(pool, page_ids, impl="auto"):
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "pallas":
         return _gather(pool, page_ids)
     if impl == "interpret":
@@ -19,8 +17,7 @@ def page_gather(pool, page_ids, impl="auto"):
 
 
 def page_scatter(pool, page_ids, pages, impl="auto"):
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "pallas":
         return _scatter(pool, page_ids, pages)
     if impl == "interpret":

@@ -1,11 +1,12 @@
 """Paged decode attention — attention *through the page table* (Pallas TPU).
 
 This is the paper's core idea transplanted into the attention kernel: the KV
-cache lives in a shared **page pool** ([num_pages, page_size, KVH, D], the
+cache lives in a shared **page pool** ([num_pages, KVH, page_size, D], the
 UMap buffer), and each sequence owns a **page table** ([B, pages_per_seq],
 logical page -> physical pool page).  The kernel never sees a contiguous KV
 cache; the page table rides in scalar-prefetch SMEM and drives the BlockSpec
-index map, so each grid step DMAs exactly one physical page into VMEM —
+index map, so each grid step DMAs one physical page of one KV head into VMEM
+as a ``(page_size, D)`` tile (the layout's last two dims, which the TPU lowers) —
 block-table indirection à la vLLM, with the UMap twist that ``page_size``
 is an application-chosen knob (the paper's §3.6) swept by the benchmarks.
 
@@ -47,8 +48,8 @@ def _paged_kernel(table_ref, length_ref,         # scalar prefetch (SMEM)
     @pl.when(page_lo < length)
     def _attend():
         q = q_ref[0, 0].astype(jnp.float32)               # [rep, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)            # [page, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)            # [page, D]
+        k = k_ref[0, 0].astype(jnp.float32)               # [page, D]
+        v = v_ref[0, 0].astype(jnp.float32)               # [page, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         rep = q.shape[0]
@@ -77,13 +78,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     """Decode attention through a paged KV pool.
 
     q:          [B, H, D]       one query token per sequence
-    k_pool/v_pool: [P, page_size, KVH, D]  shared physical page pool
+    k_pool/v_pool: [P, KVH, page_size, D]  shared physical page pool
     page_table: [B, pages_per_seq] int32   logical -> physical page
     lengths:    [B] int32       tokens currently valid per sequence
     returns     [B, H, D]
     """
     b, h, d = q.shape
-    p_total, page_size, kvh, _ = k_pool.shape
+    p_total, kvh, page_size, _ = k_pool.shape
     pages_per_seq = page_table.shape[1]
     assert h % kvh == 0
     rep = h // kvh
@@ -101,10 +102,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, rep, d),
                          lambda b_, g, pi, table, lens: (b_, g, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda b_, g, pi, table, lens: (table[b_, pi], 0, g, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda b_, g, pi, table, lens: (table[b_, pi], 0, g, 0)),
+            pl.BlockSpec((1, 1, page_size, d),
+                         lambda b_, g, pi, table, lens: (table[b_, pi], g, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, d),
+                         lambda b_, g, pi, table, lens: (table[b_, pi], g, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, d),
                                lambda b_, g, pi, table, lens: (b_, g, 0, 0)),

@@ -2,15 +2,13 @@
 
 from __future__ import annotations
 
-import jax
-
+from .. import resolve_impl
 from .kernel import paged_attention as _pallas
 from .ref import paged_attention_ref
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lengths, impl="auto"):
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "pallas":
         return _pallas(q, k_pool, v_pool, page_table, lengths)
     if impl == "interpret":

@@ -2,7 +2,7 @@
 
 Layout (per decoder layer-stack):
 
-  k_pool / v_pool : [L, num_pages, page_size, KVH, D]   the UMap buffer
+  k_pool / v_pool : [L, num_pages, KVH, page_size, D]   the UMap buffer
   page tables     : host-side, per sequence (allocator.py free list)
 
 ``page_size`` (tokens per page) is the paper's §3.6 knob — benchmarks sweep
@@ -107,8 +107,8 @@ class PagedKVCache:
     def __init__(self, cfg: PagedKVConfig):
         self.cfg = cfg
         dt = jnp.dtype(cfg.dtype)
-        shape = (cfg.num_layers, cfg.num_pages, cfg.page_size,
-                 cfg.num_kv_heads, cfg.head_dim)
+        shape = (cfg.num_layers, cfg.num_pages, cfg.num_kv_heads,
+                 cfg.page_size, cfg.head_dim)
         self.k_pool = jnp.zeros(shape, dt)
         self.v_pool = jnp.zeros(shape, dt)
         self.allocator = PageAllocator(cfg.num_pages)
@@ -149,8 +149,9 @@ class PagedKVCache:
         if pad:
             k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kp = k.reshape(k.shape[0], n_pages, ps, *k.shape[2:])
-        vp = v.reshape(v.shape[0], n_pages, ps, *v.shape[2:])
+        # [L, S, KVH, D] -> pool pages [L, n, KVH, ps, D]
+        kp = k.reshape(k.shape[0], n_pages, ps, *k.shape[2:]).swapaxes(2, 3)
+        vp = v.reshape(v.shape[0], n_pages, ps, *v.shape[2:]).swapaxes(2, 3)
         # Pool updates stay under the lock: the functional
         # ``pool = pool.at[...].set(...)`` read-modify-write would lose a
         # concurrent writer's pages otherwise (dispatch is async, so the
@@ -211,8 +212,10 @@ class PagedKVCache:
             else:
                 page = self.allocator.pages_of(seq_id)[idx]
             slot = pos % ps
-            self.k_pool = self.k_pool.at[:, page, slot].set(k.astype(self.k_pool.dtype))
-            self.v_pool = self.v_pool.at[:, page, slot].set(v.astype(self.v_pool.dtype))
+            self.k_pool = self.k_pool.at[:, page, :, slot].set(
+                k.astype(self.k_pool.dtype))
+            self.v_pool = self.v_pool.at[:, page, :, slot].set(
+                v.astype(self.v_pool.dtype))
             self.seq_len[seq_id] = pos + 1
         if pos % ps == 0:               # observe at page granularity
             self._observe(seq_id, pos // ps)    # outside the lock: may evict

@@ -45,6 +45,10 @@ def main(argv=None) -> int:
     import repro.models as M
     from ..configs.registry import get_config, get_smoke_config
     from ..serve.engine import EngineConfig, Request, ServeEngine
+    from .runtime import device_summary, enable_compile_cache
+
+    enable_compile_cache()
+    print("device:", device_summary())
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.input_mode != "tokens" or cfg.is_encdec:

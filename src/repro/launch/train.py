@@ -56,6 +56,10 @@ def main(argv=None) -> int:
     from ..train.optimizer import AdamWConfig
     from ..train.train_step import TrainConfig
     from ..train.trainer import Trainer, TrainerConfig
+    from .runtime import device_summary, enable_compile_cache
+
+    enable_compile_cache()
+    print("device:", device_summary())
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.input_mode != "tokens" or cfg.is_encdec:

@@ -26,6 +26,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import with_logical_constraint as wlc
 from .common import ParamSpec, fan_in_init, normal_init
@@ -152,16 +154,6 @@ def _moe_forward_shard_local(p: dict, x: jax.Array, top_k: int,
     each device's own experts, and a reverse all_to_all returns outputs —
     shard-count-sized traffic instead of GSPMD's replicate+all-reduce.
     """
-    from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax: pre-promotion location + check_rep kwarg
-        from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-        def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-            return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, check_rep=check_vma)
-
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     b, s, d = x.shape
     bspec = data_axes if x.shape[0] % max(

@@ -180,6 +180,7 @@ class ServeEngine:
                       "per_tenant": {}}
         self._caches = self._init_caches()
         self._decode = jax.jit(partial(T.decode_step, cfg))
+        self._prefill = jax.jit(partial(T.prefill, cfg))   # one per bucket
 
     def register_telemetry(self, registry=None, label=None) -> str:
         """Opt this engine into the telemetry registry (DESIGN.md §15).
@@ -509,8 +510,8 @@ class ServeEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :S] = prompt
         cache = T.init_cache(self.cfg, 1, bucket + 8 + self.cfg.num_meta_tokens)
-        _, cache = T.prefill(self.cfg, self.params,
-                             {"tokens": jnp.asarray(tokens)}, cache)
+        _, cache = self._prefill(self.params, {"tokens": jnp.asarray(tokens)},
+                                 cache)
         return cache
 
     def _prefill_into_pool(self, req: Request) -> None:

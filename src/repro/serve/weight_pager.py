@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.pattern import AccessPatternClassifier
+from ..kernels.page_gather.kernel import LANES
 from ..kernels.page_gather.ops import page_gather, page_scatter
 
 PyTree = Any
@@ -96,6 +97,11 @@ class RegionLayerSource:
     ``pool_pages`` pages (default: enough for every layer) evicted
     layer-at-a-time FIFO.
 
+    The pool is laid out ``[pool_pages, page_elems // 128, 128]`` (rows of
+    128 lanes) whenever a page holds a multiple of 128 elements, which is
+    the layout the TPU page kernels read and write in place; ``impl``
+    selects the kernel implementation (``kernels.resolve_impl``).
+
     ``pin_fast_layers`` is the tiered-store opt-in (DESIGN.md §14.3): when
     the region's store is a ``TieredStore`` (host fast tier over an
     NVMe/remote weight file), the named layers' page ranges are advised
@@ -106,8 +112,9 @@ class RegionLayerSource:
 
     def __init__(self, region, specs: Sequence[dict], device=None,
                  pool_pages: Optional[int] = None,
-                 pin_fast_layers: Sequence[int] = ()):
+                 pin_fast_layers: Sequence[int] = (), impl: str = "auto"):
         self.region = region
+        self.impl = impl
         self.specs = list(specs)
         if pin_fast_layers:
             if not getattr(region, "tiered", False):
@@ -124,6 +131,9 @@ class RegionLayerSource:
         if any(np.dtype(s["dtype"]) != self.dtype for s in self.specs):
             raise ValueError("RegionLayerSource requires a uniform dtype")
         self.page_elems = region.page_size // self.dtype.itemsize
+        self.page_shape = ((self.page_elems // LANES, LANES)
+                           if self.page_elems % LANES == 0
+                           else (self.page_elems,))
         need = max(s["npages"] for s in self.specs)
         self.pool_pages = (sum(s["npages"] for s in self.specs)
                            if pool_pages is None else pool_pages)
@@ -131,7 +141,7 @@ class RegionLayerSource:
             raise ValueError(
                 f"pool_pages {self.pool_pages} cannot hold the largest "
                 f"layer ({need} pages)")
-        self._pool = jnp.zeros((self.pool_pages, self.page_elems),
+        self._pool = jnp.zeros((self.pool_pages,) + self.page_shape,
                                jnp.dtype(self.dtype))
         self._layer_slots: Dict[int, List[int]] = {}   # layer -> pool slots
         self._fifo: List[int] = []                     # layer install order
@@ -185,7 +195,9 @@ class RegionLayerSource:
         if self.region.service.config.zero_copy_leases:
             with self.region.lease_run(spec["first_page"],
                                        spec["npages"]) as run:
-                dev = [jax.device_put(v.view(self.dtype), self.device)
+                dev = [jax.device_put(
+                           v.view(self.dtype).reshape(self.page_shape),
+                           self.device)
                        for v in run.views]
                 # device_put dispatches asynchronously FROM the leased
                 # buffer; the slots must stay pinned until the copies land.
@@ -198,7 +210,7 @@ class RegionLayerSource:
         self.staging_copies += spec["npages"]
         return [jax.device_put(
                     self.region.read((spec["first_page"] + i) * ps, ps)
-                    .view(self.dtype), self.device)
+                    .view(self.dtype).reshape(self.page_shape), self.device)
                 for i in range(spec["npages"])]
 
     def __getitem__(self, i: int) -> jax.Array:
@@ -215,7 +227,8 @@ class RegionLayerSource:
                     # dispatch order under the lock does not order execution
                     # against a donated write.
                     flat = page_gather(self._pool,
-                                       jnp.asarray(slots, jnp.int32))
+                                       jnp.asarray(slots, jnp.int32),
+                                       impl=self.impl)
                     flat.block_until_ready()
                     break
                 ev = self._inflight.get(i)
@@ -231,7 +244,7 @@ class RegionLayerSource:
                         slots = self._take_slots(spec["npages"])
                         self._pool = page_scatter(
                             self._pool, jnp.asarray(slots, jnp.int32),
-                            jnp.stack(dev_pages))
+                            jnp.stack(dev_pages), impl=self.impl)
                         self._layer_slots[i] = slots
                         self._fifo.append(i)
                 finally:

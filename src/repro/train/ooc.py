@@ -87,6 +87,7 @@ class OOCTrainerConfig:
     #   moments_fast_tier_bytes when set — the packed image is the base tier
     hot_window_leaves: int = 0        # leading leaves tier-hinted "hot"
     pool_pages: int = 0               # device pool for the param source (0 = all)
+    kernel_impl: str = "auto"         # page kernels of the param source
     max_step_retries: int = 3         # sweep retries after an I/O fault
     total_steps: int = 100
     ckpt_dir: Optional[str] = None
@@ -206,7 +207,7 @@ class OOCTrainer:
                                  self._p_specs, self.treedef)
         self.source = RegionLayerSource(
             self._params.region, self._p_specs,
-            pool_pages=ocfg.pool_pages or None)
+            pool_pages=ocfg.pool_pages or None, impl=ocfg.kernel_impl)
 
         if mv_factory is None:
             if ocfg.moments_tier_chain:

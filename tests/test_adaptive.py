@@ -273,6 +273,37 @@ def test_runtime_policy_swap_preserves_residency():
         uunmap(r)
 
 
+def test_policy_swap_keeps_pages_mid_writeback_evictable():
+    """Regression: a page posted for write-back (CLEANING) when the policy
+    is swapped returns to PRESENT once cleaned.  The new policy must track
+    it, or its slot can never be reclaimed: the OOC trainer's adaptive
+    moments region then livelocked in ``lease_run`` on a stripe short of
+    one slot."""
+    from repro.core import PageState
+
+    r, data, _ = make_region(nbytes=16 * 4096, slots=4)
+    svc = r.service
+    try:
+        r.write(0, np.full(4096, 7, np.uint8))          # page 0 dirty
+        key = (r.region_id, 0)
+        shard = svc._shard_of(key)
+        with shard.lock:
+            e = shard.table.get(key)
+            e.state = PageState.CLEANING                # as a poster does
+        svc.set_eviction_policy("swa")
+        with shard.lock:                                # as a cleaner does
+            e.state = PageState.PRESENT
+            shard.table.mark_clean(e)
+        assert key in shard.policy.pick_victims(svc.buffer.num_slots,
+                                                lambda k: True)
+        for pno in range(1, 16):                        # churn past page 0
+            assert np.array_equal(r.read(pno * 4096, 4096),
+                                  data[pno * 4096:(pno + 1) * 4096])
+        assert svc.table.get(key) is None               # page 0 was evicted
+    finally:
+        uunmap(r)
+
+
 # ----------------------------------------------------- regression (baseline)
 
 

@@ -79,8 +79,8 @@ def test_flash_attention_block_shape_independence():
 def test_paged_attention_sweep(dtype, b, h, kvh, d, pages, ps, pps):
     rng = np.random.default_rng(0)
     q = rand(0, (b, h, d), dtype)
-    kp = rand(1, (pages, ps, kvh, d), dtype)
-    vp = rand(2, (pages, ps, kvh, d), dtype)
+    kp = rand(1, (pages, kvh, ps, d), dtype)
+    vp = rand(2, (pages, kvh, ps, d), dtype)
     table = jnp.asarray(
         rng.choice(pages, size=(b, pps), replace=False), jnp.int32)
     lengths = jnp.asarray(rng.integers(1, pps * ps + 1, size=b), jnp.int32)
@@ -105,8 +105,8 @@ def test_paged_attention_page_size_invariance():
     outs = []
     for ps in (8, 16, 32):
         n = S // ps
-        kp = k_seq.reshape(b * n, ps, kvh, d)
-        vp = v_seq.reshape(b * n, ps, kvh, d)
+        kp = k_seq.reshape(b * n, ps, kvh, d).swapaxes(1, 2)
+        vp = v_seq.reshape(b * n, ps, kvh, d).swapaxes(1, 2)
         table = jnp.arange(b * n, dtype=jnp.int32).reshape(b, n)
         outs.append(paged_attention(q, kp, vp, table, lengths, interpret=True))
     for o in outs[1:]:
@@ -146,12 +146,36 @@ def test_page_scatter_property(p, elems, seed):
     np.testing.assert_allclose(out, ref)
 
 
-def test_gather_scatter_roundtrip():
-    """UFFDIO_COPY semantics: install then read back the exact page."""
-    pool = jnp.zeros((8, 128), jnp.float32)
+@pytest.mark.parametrize("page_shape", [
+    (128,),             # flat page, one row of lanes
+    (4, 256),           # 2-D page, viewed as 8 rows
+    (2, 16, 64),        # a KV pool page [KVH, ps, D]
+])
+def test_gather_scatter_roundtrip(page_shape):
+    """UFFDIO_COPY semantics: install then read back the exact page, for
+    pools of any rank."""
+    pool = jnp.zeros((8,) + page_shape, jnp.float32)
     ids = jnp.asarray([3, 5], jnp.int32)
-    pages = jnp.asarray(np.random.default_rng(0).normal(size=(2, 128)),
-                        jnp.float32)
+    pages = jnp.asarray(
+        np.random.default_rng(0).normal(size=(2,) + page_shape), jnp.float32)
     pool = page_scatter(pool, ids, pages, interpret=True)
+    assert pool.shape == (8,) + page_shape
     back = page_gather(pool, ids, interpret=True)
+    assert back.shape == (2,) + page_shape
     np.testing.assert_allclose(back, pages)
+    np.testing.assert_allclose(pool[1], 0.0)     # untouched slots stay zero
+
+
+@pytest.mark.parametrize("kernel", ["gather", "scatter"])
+def test_page_kernels_refuse_pages_off_the_lane_width(kernel):
+    """A page that is not a whole number of 128-lane rows cannot be a TPU
+    block: the kernel path raises instead of silently using the reference."""
+    pool = jnp.zeros((4, 3, 40), jnp.float32)          # 120 elements/page
+    ids = jnp.asarray([1], jnp.int32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        if kernel == "gather":
+            page_gather(pool, ids, interpret=True)
+        else:
+            page_scatter(pool, ids, jnp.ones((1, 3, 40)), interpret=True)
+    # the jnp reference has no such constraint
+    np.testing.assert_allclose(page_gather_ref(pool, ids), pool[1:2])

@@ -389,6 +389,87 @@ def test_three_concurrent_lease_runs_dont_deadlock():
         uunmap(r)
 
 
+@pytest.mark.parametrize("slots,shards", [
+    (8, 0), (16, 4), (20, 5), (57, 0), (64, 16), (63, 16), (100, 7)])
+def test_every_capped_run_fits_its_stripes(slots, shards):
+    """Slot free lists are stripe-private, so a run can be granted only if
+    no stripe holds more of its pages than it owns slots.  The stripe map
+    must guarantee that for every run up to the length cap: with
+    ``hash(key) % N`` a 28-page run put 5 pages on a 4-slot stripe and
+    ``lease_run`` retried forever (the OOC trainer's livelock)."""
+    from repro.core import PagingService
+
+    svc = PagingService(UMapConfig(page_size=4096, buffer_size=slots * 4096,
+                                   num_fillers=8, num_evictors=1,
+                                   shards=shards))
+    try:
+        owned = [len(s.free) for s in svc.shards]
+        n = len(owned)
+        cap = min(svc.config.max_lease_run, svc.buffer.num_slots // 2)
+        for rid in range(4):
+            for first in range(300):
+                need = [0] * n
+                for pno in range(first, first + cap):
+                    need[svc._shard_index((rid, pno))] += 1
+                assert all(k <= o for k, o in zip(need, owned)), \
+                    (rid, first, need, owned)
+            if n > 1:    # a stride of N pages still spreads over stripes
+                hit = {svc._shard_index((rid, 3 + j * n)) for j in range(64)}
+                assert len(hit) > n // 2
+    finally:
+        svc.close()
+
+
+class _SlowFirstFailingStore(HostArrayStore):
+    """Page 0 reads slowly; the first read covering page 3 fails."""
+
+    def __init__(self, data, ps):
+        super().__init__(data)
+        self.ps = ps
+        self.failed = False
+
+    def _hook(self, offset, nbytes):
+        if offset == 0:
+            time.sleep(0.2)
+        if not self.failed and offset <= 3 * self.ps < offset + nbytes:
+            self.failed = True
+            raise OSError("injected read fault on page 3")
+
+    def read_into(self, offset, buf):
+        self._hook(offset, buf.nbytes)
+        return super().read_into(offset, buf)
+
+    def read_into_batch(self, offset, bufs):
+        self._hook(offset, sum(b.nbytes for b in bufs))
+        return super().read_into_batch(offset, bufs)
+
+
+def test_lease_run_raises_when_a_fill_it_posted_failed():
+    """A run waits on every fill it posts.  Page 3's fill fails while the
+    grant loop still waits on page 0; the loop would later re-fault page 3
+    and succeed, but the caller must see the error all the same (and a
+    retry then succeeds)."""
+    npages, ps = 16, 4096
+    store = _SlowFirstFailingStore(_pattern(npages * ps).copy(), ps)
+    r = umap(store, config=UMapConfig(
+        page_size=ps, buffer_size=npages * ps, num_fillers=4,
+        num_evictors=1, shards=4, max_batch_pages=1))
+    try:
+        try:
+            run = r.lease_run(0, 6)
+        except OSError as exc:
+            assert "page 3" in str(exc)
+        else:
+            run.release()
+            pytest.fail("lease_run hid the failed fill of page 3")
+        assert store.failed
+        with r.lease_run(0, 6) as run:
+            for i, v in enumerate(run.views):
+                assert np.array_equal(v, _pattern(ps, i * ps))
+    finally:
+        uunmap(r)
+
+
 def test_abandoned_write_lease_does_not_mark_dirty():
     """Regression (review finding): lease_run's abort path releases
     write-leases whose views were never handed out — they must not dirty
@@ -589,6 +670,12 @@ def test_paged_kv_lease_gathers_without_staging_and_pins_sequence():
         pages = pc.allocator.pages_of(0)
         want = jnp.take(pc.k_pool[1], jnp.asarray(pages), axis=0)
         assert jnp.allclose(ls.k, want)
+        # pages come back in the pool layout [n, KVH, ps, D]: page 1 slot 2
+        # of KV head 1 is token 6 of layer 1
+        assert ls.k.shape == (3, 2, 4, 8)
+        tok = k[1, 6, 1]
+        assert jnp.array_equal(ls.k[1, 1, 2], tok.astype(ls.k.dtype))
+        assert jnp.array_equal(ls.v[1, 1, 2], (tok + 1).astype(ls.v.dtype))
         with pytest.raises(RuntimeError, match="lease"):
             pc.release(0)                          # pinned against free
         assert pc.evict_window_prefix(0, 4) == []  # and against eviction

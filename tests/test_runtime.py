@@ -57,9 +57,25 @@ def test_paged_kv_cache_roundtrip_and_attend():
     assert cache.seq_len[7] == 10
     cache.append_token(7, k[:, 0], v[:, 0])
     assert cache.seq_len[7] == 11
+    # pool layout [L, P, KVH, ps, D]: token t of the sequence sits in page
+    # t // ps at slot t % ps of every KV head
+    assert cache.k_pool.shape == (2, 16, 2, 4, 8)
+    pages = cache.allocator.pages_of(7)
+    k, v = k.astype(cache.k_pool.dtype), v.astype(cache.v_pool.dtype)
+    np.testing.assert_array_equal(cache.k_pool[:, pages[1], :, 1],
+                                  k[:, 5])                 # token 5
+    np.testing.assert_array_equal(cache.v_pool[:, pages[2], :, 2],
+                                  v[:, 0])                 # appended token 10
     q = jnp.asarray(rng.normal(size=(1, 4, 8)), jnp.float32)
     out = cache.attend(0, q, [7], impl="ref")
     assert out.shape == (1, 4, 8) and np.isfinite(np.asarray(out)).all()
+    # the paged read equals dense GQA attention over the 11 tokens
+    ks = jnp.concatenate([k[0], k[0, :1]]).astype(jnp.float32)  # [11, KVH, D]
+    vs = jnp.concatenate([v[0], v[0, :1]]).astype(jnp.float32)
+    qg = q[0].reshape(2, 2, 8)                             # [KVH, rep, D]
+    w = jax.nn.softmax(jnp.einsum("grd,sgd->grs", qg, ks) / np.sqrt(8), -1)
+    want = jnp.einsum("grs,sgd->grd", w, vs).reshape(1, 4, 8)
+    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
     stats = cache.stats()
     assert stats["sequences"] == 1 and stats["pages_used"] == 3
     assert cache.release(7) == 3

@@ -1,0 +1,160 @@
+"""Compiles of the chip path for a TPU v5e, without a chip.
+
+The TPU compiler is installed with jax; it compiles for a described v5e
+topology and refuses what the chip would refuse (block shapes off the
+(8, 128) tiling, programs that do not fit).  Nothing runs, so these tests
+say nothing about results or times — ``chip_smoke.py`` on the chip does.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports every test file.
+"""
+
+import importlib.util
+from functools import partial
+from pathlib import Path
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels.page_gather import kernel as page_kernels
+from repro.kernels.page_gather import ops as page_ops
+from repro.kernels.paged_attention import kernel as attn_kernel
+from repro.kernels.paged_attention import ops as attn_ops
+from repro.models import transformer as T
+from repro.serve.engine import EngineConfig, ServeEngine
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One v5e chip of a described 2x2 host, with the persistent compile
+    cache off (an entry compiled for a described chip cannot be read back
+    here, and would only warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(tree, sharding):
+    return jax.tree.map(lambda s: _sds(s.shape, s.dtype, sharding), tree)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# 64 KiB fp32 pages as the weight pool lays them out ([P, rows, 128]) and
+# as a flat [P, elems] pool; 512 KiB pages as chip_smoke's trainer uses them.
+@pytest.mark.parametrize("pool_shape", [
+    (1024, 128, 128),
+    (1024, 16384),
+    (256, 1024, 128),
+])
+@pytest.mark.parametrize("op", ["gather", "scatter"])
+def test_page_kernels_compile_for_v5e(one_chip, pool_shape, op):
+    n = 64
+    pool = _sds(pool_shape, jnp.float32, one_chip)
+    ids = _sds((n,), jnp.int32, one_chip)
+    if op == "gather":
+        compiled = page_kernels.page_gather.lower(pool, ids).compile()
+    else:
+        pages = _sds((n,) + pool_shape[1:], jnp.float32, one_chip)
+        compiled = page_kernels.page_scatter.lower(pool, ids, pages).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("b,h,kvh,d,ps", [
+    (8, 9, 3, 64, 16),        # smollm-135m decode
+    (32, 32, 8, 128, 16),     # llama3-8b-class decode
+])
+def test_paged_attention_compiles_for_v5e(one_chip, b, h, kvh, d, ps):
+    pages, per_seq = 1024, 32
+    args = (_sds((b, h, d), jnp.bfloat16, one_chip),
+            _sds((pages, kvh, ps, d), jnp.bfloat16, one_chip),
+            _sds((pages, kvh, ps, d), jnp.bfloat16, one_chip),
+            _sds((b, per_seq), jnp.int32, one_chip),
+            _sds((b,), jnp.int32, one_chip))
+    _assert_kernel(attn_kernel.paged_attention.lower(*args).compile())
+
+
+@pytest.fixture(scope="module")
+def smollm(one_chip):
+    """smollm-135m's parameters and engine caches as shapes on one chip."""
+    cfg = get_config("smollm-135m")
+    ecfg = EngineConfig(max_batch=8, page_size=16, num_pages=512,
+                        max_pages_per_seq=32)
+    params = jax.eval_shape(partial(T.init_params, cfg), jax.random.key(0))
+    engine = SimpleNamespace(cfg=cfg, ecfg=ecfg, plan=cfg.decoder_plan())
+    caches = jax.eval_shape(partial(ServeEngine._init_caches, engine))
+    return cfg, ecfg, _on(params, one_chip), _on(caches, one_chip)
+
+
+def test_engine_decode_step_compiles_for_v5e(one_chip, smollm):
+    cfg, ecfg, params, caches = smollm
+    b = ecfg.max_batch
+    compiled = jax.jit(partial(T.decode_step, cfg)).lower(
+        params, caches, _sds((b,), jnp.int32, one_chip),
+        _sds((b,), jnp.int32, one_chip)).compile()
+    logits = compiled.out_info[0]
+    assert logits.shape == (b, cfg.padded_vocab)
+
+
+def test_engine_prefill_compiles_for_v5e(one_chip, smollm):
+    cfg, _, params, _ = smollm
+    bucket = 64
+    cache = _on(jax.eval_shape(partial(T.init_cache, cfg, 1, bucket + 8)),
+                one_chip)
+    tokens = {"tokens": _sds((1, bucket), jnp.int32, one_chip)}
+    compiled = jax.jit(partial(T.prefill, cfg)).lower(
+        params, tokens, cache).compile()
+    assert compiled.out_info[0].shape == (1, cfg.d_model)
+
+
+@pytest.mark.parametrize("op", ["page_gather", "page_scatter",
+                                "paged_attention"])
+def test_chip_smoke_kernel_check_sees_the_kernel(one_chip, op):
+    """``chip_smoke.py``'s proof that a phase ran the Pallas kernel accepts
+    the ``impl="pallas"`` dispatch and refuses the jnp reference."""
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    if op == "paged_attention":
+        fn = attn_ops.paged_attention
+        args = (_sds((8, 9, 64), jnp.bfloat16, one_chip),
+                _sds((64, 3, 16, 64), jnp.bfloat16, one_chip),
+                _sds((64, 3, 16, 64), jnp.bfloat16, one_chip),
+                _sds((8, 8), jnp.int32, one_chip),
+                _sds((8,), jnp.int32, one_chip))
+    else:
+        fn = getattr(page_ops, op)
+        args = (_sds((64, 1024, 128), jnp.float32, one_chip),
+                _sds((16,), jnp.int32, one_chip))
+        if op == "page_scatter":
+            args += (_sds((16, 1024, 128), jnp.float32, one_chip),)
+    chip_smoke._check_kernel_lowered(fn, "pallas", *args)
+    with pytest.raises(RuntimeError, match="no tpu_custom_call"):
+        chip_smoke._check_kernel_lowered(fn, "ref", *args)
