@@ -91,6 +91,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple, TYPE_CHECKING
 
+from ..telemetry import spans
 from .buffer import EvictionPolicy, PageBuffer, make_policy
 from .config import UMapConfig
 from .lease import LeaseRun, PageLease
@@ -794,7 +795,9 @@ class PagingService:
                 self._observe_faults(region, [page_no])
             if deadline is not None and time.monotonic() >= deadline:
                 return None        # dispatched fill proceeds; wait abandoned
-            waitee.event.wait(timeout=0.05)
+            # one span per bounded wait; a page counts at its first
+            with spans.span("umap.pager.fault_wait", pages=int(first_attempt)):
+                waitee.event.wait(timeout=0.05)
             if waitee.error is not None:
                 raise IOError(
                     f"fill of page {page_no} in region "
@@ -1750,8 +1753,10 @@ class PagingService:
         # exception fails the whole run: slots go back to their shards and
         # every fault waiter raises IOError (DESIGN.md §14.4).
         try:
-            self._io_retry(lambda: region.store.read_into_batch(
-                entries[0].key[1] * region.page_size, bufs))
+            with spans.span("umap.pager.fill", pages=len(bufs),
+                            bytes=sum(b.nbytes for b in bufs)):
+                self._io_retry(lambda: region.store.read_into_batch(
+                    entries[0].key[1] * region.page_size, bufs))
         except Exception as exc:
             self._release_fill_slots(zip(entries, slots))
             self._fail_fills(entries, exc)
@@ -1795,11 +1800,12 @@ class PagingService:
         # I/O outside all locks.  On a store/callback exception the slot is
         # returned and the error propagates to every waiter (§14.4).
         try:
-            if region.fill_callback is not None:
-                region.fill_callback(entry.key[1], buf[:nbytes])
-            else:
-                self._io_retry(lambda: region.store.read_into(
-                    entry.key[1] * region.page_size, buf[:nbytes]))
+            with spans.span("umap.pager.fill", pages=1, bytes=nbytes):
+                if region.fill_callback is not None:
+                    region.fill_callback(entry.key[1], buf[:nbytes])
+                else:
+                    self._io_retry(lambda: region.store.read_into(
+                        entry.key[1] * region.page_size, buf[:nbytes]))
         except Exception as exc:
             self._release_fill_slots([(entry, slot)])
             self._fail_fills([entry], exc)
@@ -2000,12 +2006,14 @@ class PagingService:
         then per-shard atomic clean-bit clearing + waiter wakeup."""
         bufs = [self.buffer.slot_view(e.slot, region.page_nbytes(e.key[1]))
                 for e in run]
-        if len(run) == 1:
-            self._io_retry(lambda: region.store.write_from(
-                run[0].key[1] * region.page_size, bufs[0]))
-        else:
-            self._io_retry(lambda: region.store.write_from_batch(
-                run[0].key[1] * region.page_size, bufs))
+        with spans.span("umap.pager.writeback", pages=len(run),
+                        bytes=sum(b.nbytes for b in bufs)):
+            if len(run) == 1:
+                self._io_retry(lambda: region.store.write_from(
+                    run[0].key[1] * region.page_size, bufs[0]))
+            else:
+                self._io_retry(lambda: region.store.write_from_batch(
+                    run[0].key[1] * region.page_size, bufs))
 
     def _evictor_loop(self, worker_id: int) -> None:
         # Opportunistic batch drain: after blocking on the first item, pull

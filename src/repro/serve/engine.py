@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -44,6 +43,7 @@ from ..configs.base import ModelConfig
 from ..core.hints import deadline_headroom_s, fair_shares
 from ..kvcache.allocator import OutOfPages, PageAllocator
 from ..models import transformer as T
+from ..telemetry import spans
 
 
 @dataclasses.dataclass
@@ -177,10 +177,21 @@ class ServeEngine:
                       "victim_evictions": 0, "cow_copies": 0,
                       "shared_pages_mapped": 0, "prefix_hits": 0,
                       "prefix_drops": 0, "peak_pages_used": 0,
+                      # KV positions the prefill program computed, and how
+                      # many of them a shared prefix's pages already held
+                      "prefill_tokens": 0, "prefill_tokens_held": 0,
                       "per_tenant": {}}
         self._caches = self._init_caches()
-        self._decode = jax.jit(partial(T.decode_step, cfg))
-        self._prefill = jax.jit(partial(T.prefill, cfg))   # one per bucket
+
+        # named functions, so that the trace names the programs
+        def decode_step(params, caches, tokens, cur_pos):
+            return T.decode_step(cfg, params, caches, tokens, cur_pos)
+
+        def prefill(params, batch, cache):
+            return T.prefill(cfg, params, batch, cache)
+
+        self._decode = jax.jit(decode_step)
+        self._prefill = jax.jit(prefill)   # one per bucket
 
     def register_telemetry(self, registry=None, label=None) -> str:
         """Opt this engine into the telemetry registry (DESIGN.md §15).
@@ -521,61 +532,74 @@ class ServeEngine:
         With a matching registered prefix, the page-aligned shared span is
         *mapped* (refcount++) instead of allocated; only the tail past the
         shared tokens is installed, COW-copying the boundary page when the
-        tail writes into it (DESIGN.md §16.4).
+        tail writes into it (DESIGN.md §16.4).  The whole prompt is still
+        prefilled: ``prefill_tokens_held`` counts the positions the shared
+        pages already held.
         """
         t0 = time.perf_counter()
         e = self.ecfg
         ps = e.page_size
         prompt = req.prompt[:-1]
         S = len(prompt)
-        cache = self._run_prefill(prompt)
-        lane = self._free_lanes.pop()
-        eff = S + self.cfg.num_meta_tokens
-        eff_final = eff + 1                     # incl. pending last token
-        need_total = -(-eff_final // ps) + 1
+        with spans.span("umap.engine.prefill", rid=req.rid) as sp:
+            cache = self._run_prefill(prompt)
+            lane = self._free_lanes.pop()
+            eff = S + self.cfg.num_meta_tokens
+            eff_final = eff + 1                     # incl. pending last token
+            need_total = -(-eff_final // ps) + 1
 
-        entry = self._match_prefix(req.prompt)
-        n_shared = 0
-        shared_tok = 0
-        if entry is not None:
-            shared_tok = min(entry.n_tokens, eff)
-            n_shared = min(-(-shared_tok // ps) if shared_tok else 0,
-                           len(entry.pages), need_total)
-            if n_shared:
-                self.allocator.share(entry.seq_id, req.rid, n_shared)
-                entry.hits += 1
-                entry.last_used = time.time()
-                self.stats["prefix_hits"] += 1
-                self.stats["shared_pages_mapped"] = self.allocator.shared_mapped
-        tail = need_total - n_shared
-        if tail > 0:
-            self.allocator.alloc(req.rid, tail)
+            entry = self._match_prefix(req.prompt)
+            n_shared = 0
+            shared_tok = 0
+            if entry is not None:
+                shared_tok = min(entry.n_tokens, eff)
+                n_shared = min(-(-shared_tok // ps) if shared_tok else 0,
+                               len(entry.pages), need_total)
+                if n_shared:
+                    self.allocator.share(entry.seq_id, req.rid, n_shared)
+                    entry.hits += 1
+                    entry.last_used = time.time()
+                    self.stats["prefix_hits"] += 1
+                    self.stats["shared_pages_mapped"] = \
+                        self.allocator.shared_mapped
+            held = shared_tok if n_shared else 0
+            sp.add(tokens=eff, held_tokens=held)
+            self.stats["prefill_tokens"] += eff
+            self.stats["prefill_tokens_held"] += held
+            tail = need_total - n_shared
+            if tail > 0:
+                self.allocator.alloc(req.rid, tail)
 
-        # install start: first page this request must write itself
-        if n_shared and shared_tok % ps and eff > shared_tok:
-            # prefill tail spills into the shared boundary page: first
-            # divergent write → COW now.  No device copy needed — the whole
-            # page is rewritten below from this request's own prefill (the
-            # shared span re-derives bit-identically; positions past eff in
-            # the page are masked by `len`).
-            self.allocator.make_private(req.rid, n_shared - 1)
-            self.stats["cow_copies"] = self.allocator.cow_copies
-            a0 = (n_shared - 1) * ps
-        else:
-            a0 = n_shared * ps
-        pages = self.allocator.pages_of(req.rid)
+            # install start: first page this request must write itself
+            if n_shared and shared_tok % ps and eff > shared_tok:
+                # prefill tail spills into the shared boundary page: first
+                # divergent write → COW now.  No device copy needed — the
+                # whole page is rewritten below from this request's own
+                # prefill (the shared span re-derives bit-identically;
+                # positions past eff in the page are masked by `len`).
+                self.allocator.make_private(req.rid, n_shared - 1)
+                self.stats["cow_copies"] = self.allocator.cow_copies
+                a0 = (n_shared - 1) * ps
+            else:
+                a0 = n_shared * ps
+            pages = self.allocator.pages_of(req.rid)
 
-        for i, (seg, c) in enumerate(zip(self.plan, self._caches)):
-            if not seg.has_attention:
-                # recurrent caches: copy prefilled state into the lane
-                self._caches[i] = _copy_state_lane(c, cache[i], lane, eff)
-                continue
-            # move prefilled contiguous KV into pool pages for this lane
-            k = cache[i]["k"][:, 0, a0:eff]
-            v = cache[i]["v"][:, 0, a0:eff]
-            self._caches[i] = _install_pages(
-                c, k, v, pages[a0 // ps:], lane, ps, e.max_pages_per_seq,
-                prior_state=cache[i] if seg.has_mamba else None)
+            with spans.span("umap.engine.install", rid=req.rid,
+                            tokens=eff - a0):
+                for i, (seg, c) in enumerate(zip(self.plan, self._caches)):
+                    if not seg.has_attention:
+                        # recurrent caches: copy prefilled state into the lane
+                        self._caches[i] = _copy_state_lane(c, cache[i], lane,
+                                                           eff)
+                        continue
+                    # move prefilled contiguous KV into pool pages for this
+                    # lane
+                    k = cache[i]["k"][:, 0, a0:eff]
+                    v = cache[i]["v"][:, 0, a0:eff]
+                    self._caches[i] = _install_pages(
+                        c, k, v, pages[a0 // ps:], lane, ps,
+                        e.max_pages_per_seq,
+                        prior_state=cache[i] if seg.has_mamba else None)
         self.active[req.rid] = req
         self.lane_of[req.rid] = lane
         self.seq_len[req.rid] = eff
@@ -590,37 +614,45 @@ class ServeEngine:
 
     def step(self) -> int:
         """One engine iteration: admit, decode the active set, retire."""
+        with spans.span("umap.engine.step") as sp:
+            lanes = self._step()
+            sp.add(lanes=lanes)
+        return lanes
+
+    def _step(self) -> int:
         t0 = time.perf_counter()
-        self._try_admit()
+        with spans.span("umap.engine.admit"):
+            self._try_admit()
         if not self.active:
             return 0
         e = self.ecfg
         ps = e.page_size
         now = time.time()
         live: List[int] = []
-        for rid, req in list(self.active.items()):
-            # straggler mitigation: requeue requests past their deadline
-            if req.deadline_s and now - req.submitted_at > req.deadline_s:
-                self._evict(rid, requeue=True)
-                continue
-            live.append(rid)
+        with spans.span("umap.engine.page_work"):
+            for rid, req in list(self.active.items()):
+                # straggler mitigation: requeue requests past their deadline
+                if req.deadline_s and now - req.submitted_at > req.deadline_s:
+                    self._evict(rid, requeue=True)
+                    continue
+                live.append(rid)
 
-        # Host-side page work for lanes about to write a page: boundary
-        # allocation (reclaiming from over-share tenants on pressure) and
-        # COW of shared pages.  `live` is rebuilt, never mutated mid-scan
-        # (a victim eviction may remove ANY rid, including ones already
-        # passed), so no lane's allocation is silently skipped.
-        survivors: List[int] = []
-        for rid in live:
-            if rid not in self.active:      # evicted as an earlier victim
-                continue
-            pos = self.seq_len[rid]
-            if pos % ps == 0 and not self._alloc_decode_page(rid):
-                continue                     # rid was evicted + requeued
-            if not self._ensure_private(rid, pos // ps):
-                continue
-            survivors.append(rid)
-        live = [r for r in survivors if r in self.active]
+            # Host-side page work for lanes about to write a page: boundary
+            # allocation (reclaiming from over-share tenants on pressure)
+            # and COW of shared pages.  `live` is rebuilt, never mutated
+            # mid-scan (a victim eviction may remove ANY rid, including ones
+            # already passed), so no lane's allocation is silently skipped.
+            survivors: List[int] = []
+            for rid in live:
+                if rid not in self.active:  # evicted as an earlier victim
+                    continue
+                pos = self.seq_len[rid]
+                if pos % ps == 0 and not self._alloc_decode_page(rid):
+                    continue                 # rid was evicted + requeued
+                if not self._ensure_private(rid, pos // ps):
+                    continue
+                survivors.append(rid)
+            live = [r for r in survivors if r in self.active]
         if not live:
             return 0
 
@@ -634,9 +666,11 @@ class ServeEngine:
             cur[lane] = self.seq_len[rid]
         self._sync_tables(live)
 
-        logits, self._caches = self._decode(
-            self.params, self._caches, jnp.asarray(tokens), jnp.asarray(cur))
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
+        with spans.span("umap.engine.decode", lanes=len(live)):
+            logits, self._caches = self._decode(
+                self.params, self._caches, jnp.asarray(tokens),
+                jnp.asarray(cur))
+            next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
         for rid in live:
             lane = self.lane_of[rid]
             req = self.active[rid]
@@ -692,20 +726,22 @@ class ServeEngine:
 
     def _sync_tables(self, live: List[int]) -> None:
         e = self.ecfg
-        table = np.zeros((e.max_batch, e.max_pages_per_seq), np.int32)
-        lens = np.zeros(e.max_batch, np.int32)
-        for rid in live:
-            lane = self.lane_of[rid]
-            table[lane] = self.allocator.table_for(rid, e.max_pages_per_seq)
-            lens[lane] = self.seq_len[rid]
-        tj = jnp.asarray(table)
-        lj = jnp.asarray(lens)
-        for i, (seg, c) in enumerate(zip(self.plan, self._caches)):
-            if seg.has_attention:
-                c = dict(c)
-                c["table"] = jnp.broadcast_to(tj, c["table"].shape)
-                c["len"] = jnp.broadcast_to(lj, c["len"].shape)
-                self._caches[i] = c
+        with spans.span("umap.engine.sync_tables", lanes=len(live)):
+            table = np.zeros((e.max_batch, e.max_pages_per_seq), np.int32)
+            lens = np.zeros(e.max_batch, np.int32)
+            for rid in live:
+                lane = self.lane_of[rid]
+                table[lane] = self.allocator.table_for(rid,
+                                                       e.max_pages_per_seq)
+                lens[lane] = self.seq_len[rid]
+            tj = jnp.asarray(table)
+            lj = jnp.asarray(lens)
+            for i, (seg, c) in enumerate(zip(self.plan, self._caches)):
+                if seg.has_attention:
+                    c = dict(c)
+                    c["table"] = jnp.broadcast_to(tj, c["table"].shape)
+                    c["len"] = jnp.broadcast_to(lj, c["len"].shape)
+                    self._caches[i] = c
 
     # ------------------------------------------------------------- eviction
 

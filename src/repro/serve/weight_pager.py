@@ -42,6 +42,7 @@ import numpy as np
 from ..core.pattern import AccessPatternClassifier
 from ..kernels.page_gather.kernel import LANES
 from ..kernels.page_gather.ops import page_gather, page_scatter
+from ..telemetry import spans
 
 PyTree = Any
 
@@ -152,6 +153,8 @@ class RegionLayerSource:
         # pager's filler pool); duplicate fetchers wait on the event.
         self._inflight: Dict[int, threading.Event] = {}
         self.staging_copies = 0     # non-lease fallback fetches (telemetry)
+        self.h2d_copies = 0         # host-to-device page copies and bytes
+        self.h2d_bytes = 0
 
     def __len__(self) -> int:
         return len(self.specs)
@@ -192,26 +195,32 @@ class RegionLayerSource:
 
     def _fetch_pages(self, spec: dict) -> List[jax.Array]:
         """Layer pages as device arrays — zero host staging via leases."""
-        if self.region.service.config.zero_copy_leases:
-            with self.region.lease_run(spec["first_page"],
-                                       spec["npages"]) as run:
-                dev = [jax.device_put(
-                           v.view(self.dtype).reshape(self.page_shape),
-                           self.device)
-                       for v in run.views]
-                # device_put dispatches asynchronously FROM the leased
-                # buffer; the slots must stay pinned until the copies land.
-                for d in dev:
-                    d.block_until_ready()
-            return dev
-        # Copy-backed fallback (UMAP_ZERO_COPY_LEASES=0): one staging
-        # memcpy per page through region.read.
-        ps = self.region.page_size
-        self.staging_copies += spec["npages"]
-        return [jax.device_put(
-                    self.region.read((spec["first_page"] + i) * ps, ps)
-                    .view(self.dtype).reshape(self.page_shape), self.device)
-                for i in range(spec["npages"])]
+        with spans.span("umap.source.fetch", pages=spec["npages"]):
+            if self.region.service.config.zero_copy_leases:
+                with self.region.lease_run(spec["first_page"],
+                                           spec["npages"]) as run:
+                    host = [v.view(self.dtype).reshape(self.page_shape)
+                            for v in run.views]
+                    with spans.transfer("h2d", host):
+                        dev = [jax.device_put(h, self.device) for h in host]
+                        # device_put dispatches asynchronously FROM the
+                        # leased buffer; the slots must stay pinned until
+                        # the copies land.
+                        for d in dev:
+                            d.block_until_ready()
+            else:
+                # Copy-backed fallback (UMAP_ZERO_COPY_LEASES=0): one
+                # staging memcpy per page through region.read.
+                ps = self.region.page_size
+                self.staging_copies += spec["npages"]
+                host = [self.region.read((spec["first_page"] + i) * ps, ps)
+                        .view(self.dtype).reshape(self.page_shape)
+                        for i in range(spec["npages"])]
+                with spans.transfer("h2d", host):
+                    dev = [jax.device_put(h, self.device) for h in host]
+        self.h2d_copies += len(host)
+        self.h2d_bytes += sum(h.nbytes for h in host)
+        return dev
 
     def __getitem__(self, i: int) -> jax.Array:
         spec = self.specs[i]

@@ -44,6 +44,10 @@ _ENGINE_COUNTERS = (
      "Registered prefixes dropped (reclaim or explicit)"),
     ("shed_requests", "umap_serve_shed_total",
      "Requests shed at admission under degraded paging (DESIGN.md §17.9)"),
+    ("prefill_tokens", "umap_serve_prefill_tokens_total",
+     "KV positions computed by admission prefills"),
+    ("prefill_tokens_held", "umap_serve_prefill_tokens_held_total",
+     "Prefilled positions that a shared prefix's pages already held"),
 )
 
 #: per-tenant stats keys exported with a ``tenant`` label (DESIGN.md §16.6);

@@ -5,8 +5,9 @@ Wraps ONE ``train.ooc.OOCTrainer`` by duck-typing: its plain-dict
 the core pager's snapshot) plus derived state/buffer gauges.  The
 underlying pager regions are expected to carry their own
 ``PagerCollector`` registrations; this collector covers only what the
-training loop itself knows — steps, retries, sweep volume, the
-zero-staging-copy invariant, and the oversubscription ratio.
+training loop itself knows — steps, retries, sweep volume, host<->device
+copies and bytes, the zero-staging-copy invariant, and the
+oversubscription ratio.
 """
 
 from __future__ import annotations
@@ -30,6 +31,14 @@ _TRAIN_COUNTERS = (
      "Checkpoints enqueued through the snapshot path (§18.4)"),
     ("quarantine_retries", "umap_train_quarantine_retries_total",
      "Quarantined pages re-posted by drain_quarantine (§17.4)"),
+    ("h2d_copies", "umap_train_h2d_copies_total",
+     "Host-to-device copies of the step (grad phase and sweep)"),
+    ("h2d_bytes", "umap_train_h2d_bytes_total",
+     "Bytes copied host to device by the step"),
+    ("d2h_copies", "umap_train_d2h_copies_total",
+     "Device-to-host copies of the step (grad phase and sweep)"),
+    ("d2h_bytes", "umap_train_d2h_bytes_total",
+     "Bytes copied device to host by the step"),
 )
 
 

@@ -40,7 +40,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
-from typing import Any, Callable, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Iterable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,7 @@ from ..core.region import umap, uunmap
 from ..core.store import HostArrayStore, TieredStore
 from ..models import transformer as T
 from ..serve.weight_pager import RegionLayerSource
+from ..telemetry import spans
 from .optimizer import adamw_elementwise, global_norm, update_scalars
 from .paged_state import (PagedOptimizerState, PagedTree, interleave_moments,
                           pack_tree, split_moments)
@@ -154,6 +155,14 @@ class OOCTrainer:
         self._mv_buf, self._mv_specs, _ = pack_tree(mv_zero, ps)
         self._num_leaves = len(self._p_specs)
 
+        self.stats = {
+            "steps": 0, "step_retries": 0, "io_errors": 0,
+            "sweep_chunks": 0, "sweep_pages": 0, "ckpt_saves": 0,
+            "quarantine_retries": 0, "last_step_s": 0.0,
+            # host<->device copies and bytes of the grad phase, the sweep
+            # and the parameter source's page fetches
+            "h2d_copies": 0, "h2d_bytes": 0, "d2h_copies": 0, "d2h_bytes": 0,
+        }
         self._params: Optional[PagedTree] = None
         self.opt: Optional[PagedOptimizerState] = None
         self.source: Optional[RegionLayerSource] = None
@@ -165,11 +174,6 @@ class OOCTrainer:
         self._scalars_jit = jax.jit(partial(update_scalars, tcfg.optimizer))
         self._pending: Optional[dict] = None
         self.metrics_log: List[dict] = []
-        self.stats = {
-            "steps": 0, "step_retries": 0, "io_errors": 0,
-            "sweep_chunks": 0, "sweep_pages": 0, "ckpt_saves": 0,
-            "quarantine_retries": 0, "last_step_s": 0.0,
-        }
         self.ckptr = (ckpt.AsyncCheckpointer(
             ocfg.ckpt_dir or "", keep=ocfg.keep_ckpts, store=ckpt_store)
             if (ocfg.ckpt_dir or ckpt_store is not None) else None)
@@ -309,36 +313,57 @@ class OOCTrainer:
         return buf[s["first_page"] * self.ocfg.page_size:][:s["nbytes"]] \
             .view(np.float32)[:n]
 
+    def _count(self, direction: str, copies: int, nbytes: int) -> None:
+        self.stats[f"{direction}_copies"] += copies
+        self.stats[f"{direction}_bytes"] += nbytes
+
+    def _moved(self, direction: str, arrays: Sequence):
+        """Count the copies of ``arrays`` and return their transfer span."""
+        self._count(direction, len(arrays), sum(int(a.nbytes) for a in arrays))
+        return spans.transfer(direction, arrays)
+
     def _device_params(self) -> PyTree:
         if self.paged:
-            leaves = [self.source[i] for i in range(self._num_leaves)]
+            src = self.source
+            copies, nbytes = src.h2d_copies, src.h2d_bytes
+            leaves = [src[i] for i in range(self._num_leaves)]
+            self._count("h2d", src.h2d_copies - copies, src.h2d_bytes - nbytes)
         else:
-            leaves = [jnp.asarray(self._leaf_resident(i, self._p_buf,
-                                                      self._p_specs))
-                      .reshape(self._p_specs[i]["shape"])
-                      for i in range(self._num_leaves)]
+            host = [self._leaf_resident(i, self._p_buf, self._p_specs)
+                    for i in range(self._num_leaves)]
+            with self._moved("h2d", host):
+                leaves = [jnp.asarray(h).reshape(self._p_specs[i]["shape"])
+                          for i, h in enumerate(host)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def _prepare_update(self, batch: dict) -> None:
         """Grad phase: read-only over params, so a fault here is retried
         by simply re-running — nothing has been stashed or mutated."""
-        params = self._device_params()
-        jb = {k: jnp.asarray(v) for k, v in batch.items()}
-        loss, metrics, grads, gnorm = self._grad_jit(params, jb)
-        scalars = self._scalars_jit(jnp.asarray(self.step_no, jnp.int32),
-                                    gnorm)
-        out = {k: float(v) for k, v in metrics.items()}
-        out["grad_norm"] = float(gnorm)
-        out["lr"] = float(scalars[1])
-        # Stash grads + scalars as host numpy: a sweep retry after an I/O
-        # fault replays EXACTLY these values (bitwise), never recomputes.
-        self._pending = {
-            "grads": [np.asarray(g, np.float32).reshape(-1)
-                      for g in jax.tree_util.tree_leaves(grads)],
-            "scalars": tuple(np.float32(np.asarray(s)) for s in scalars),
-            "metrics": out,
-            "done": set(),
-        }
+        with spans.span("umap.ooc.grad"):
+            params = self._device_params()
+            with self._moved("h2d", [*batch.values(), np.int32(self.step_no)]):
+                jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                step = jnp.asarray(self.step_no, jnp.int32)
+            loss, metrics, grads, gnorm = self._grad_jit(params, jb)
+            scalars = self._scalars_jit(step, gnorm)
+            leaves = jax.tree_util.tree_leaves(grads)
+            # every value read back: metrics, norm, lr, grads, scalars
+            back = [*metrics.values(), gnorm, scalars[1], *leaves, *scalars]
+            with self._moved("d2h", back):
+                out = {k: float(v) for k, v in metrics.items()}
+                out["grad_norm"] = float(gnorm)
+                out["lr"] = float(scalars[1])
+                # Stash grads + scalars as host numpy: a sweep retry after
+                # an I/O fault replays EXACTLY these values (bitwise),
+                # never recomputes.
+                self._pending = {
+                    "grads": [np.asarray(g, np.float32).reshape(-1)
+                              for g in leaves],
+                    "scalars": tuple(np.float32(np.asarray(s))
+                                     for s in scalars),
+                    "metrics": out,
+                    "done": set(),
+                }
 
     # ----------------------------------------------------------- sweep phase
 
@@ -371,17 +396,18 @@ class OOCTrainer:
             def noop():
                 pass
             return p_views, mv_views, noop, noop
-        p_run = self._params.region.lease_run(
-            pspec["first_page"] + p_first, p_n, write=True)
-        self._params._count_staging(p_run)
-        try:
-            mv_run = self.opt.mv.region.lease_run(
-                mvspec["first_page"] + mv_first, mv_n, write=True)
-        except BaseException:
-            for ls in p_run:
-                ls.abandon()
-            raise
-        self.opt.mv._count_staging(mv_run)
+        with spans.span("umap.ooc.lease", pages=p_n + mv_n):
+            p_run = self._params.region.lease_run(
+                pspec["first_page"] + p_first, p_n, write=True)
+            self._params._count_staging(p_run)
+            try:
+                mv_run = self.opt.mv.region.lease_run(
+                    mvspec["first_page"] + mv_first, mv_n, write=True)
+            except BaseException:
+                for ls in p_run:
+                    ls.abandon()
+                raise
+            self.opt.mv._count_staging(mv_run)
 
         def commit():
             p_run.release()
@@ -408,44 +434,57 @@ class OOCTrainer:
             # Compute every page's result BEFORE mutating any view: the
             # chunk-atomicity invariant the retry path depends on.
             results = []
-            for j, p_view in enumerate(p_views):
-                off = (ci * self.chunk_pages + j) * pe
-                le = min(pe, n - off)
-                ml = 2 * le
-                if ml <= pe:
-                    parts = (mv_views[2 * j][:ml],)
-                else:
-                    parts = (mv_views[2 * j], mv_views[2 * j + 1][:ml - pe])
-                p2, mv2 = _page_update(
-                    self.tcfg.optimizer, jnp.asarray(p_view[:le]),
-                    jnp.asarray(grads[off:off + le]),
-                    tuple(jnp.asarray(x) for x in parts),
-                    scale, lr, bc1, bc2)
-                results.append((le, np.asarray(p2), np.asarray(mv2)))
+            copies = 0
+            with spans.span("umap.ooc.page_update", pages=len(p_views)):
+                for j, p_view in enumerate(p_views):
+                    off = (ci * self.chunk_pages + j) * pe
+                    le = min(pe, n - off)
+                    ml = 2 * le
+                    if ml <= pe:
+                        parts = (mv_views[2 * j][:ml],)
+                    else:
+                        parts = (mv_views[2 * j],
+                                 mv_views[2 * j + 1][:ml - pe])
+                    host = (p_view[:le], grads[off:off + le], *parts)
+                    with spans.transfer("h2d", host):
+                        p, g, *mv = [jnp.asarray(x) for x in host]
+                    p2, mv2 = _page_update(self.tcfg.optimizer, p, g,
+                                           tuple(mv), scale, lr, bc1, bc2)
+                    # the read-back waits for the update to finish
+                    with spans.transfer("d2h", (p2, mv2)):
+                        results.append((le, np.asarray(p2), np.asarray(mv2)))
+                    copies += len(host)
         except BaseException:
             abort()
             raise
-        for j, (le, p2, mv2) in enumerate(results):
-            ml = 2 * le
-            p_views[j][:le] = p2
-            if ml <= pe:
-                mv_views[2 * j][:ml] = mv2
-            else:
-                mv_views[2 * j][:] = mv2[:pe]
-                mv_views[2 * j + 1][:ml - pe] = mv2[pe:]
-        commit()
+        # all float32: each element moves its param, grad and two moments
+        # to the device and its param and two moments back
+        el = sum(le for le, _, _ in results)
+        self._count("h2d", copies, 16 * el)
+        self._count("d2h", 2 * len(results), 12 * el)
+        with spans.span("umap.ooc.commit", pages=len(p_views) + len(mv_views)):
+            for j, (le, p2, mv2) in enumerate(results):
+                ml = 2 * le
+                p_views[j][:le] = p2
+                if ml <= pe:
+                    mv_views[2 * j][:ml] = mv2
+                else:
+                    mv_views[2 * j][:] = mv2[:pe]
+                    mv_views[2 * j + 1][:ml - pe] = mv2[pe:]
+            commit()
         self.stats["sweep_chunks"] += 1
         self.stats["sweep_pages"] += len(p_views) + len(mv_views)
 
     def _apply_pending(self) -> None:
         done = self._pending["done"]
         R = self.chunk_pages
-        for leaf in range(self._num_leaves):
-            for ci in range(-(-self._p_specs[leaf]["npages"] // R)):
-                if (leaf, ci) in done:
-                    continue
-                self._apply_chunk(leaf, ci)
-                done.add((leaf, ci))
+        with spans.span("umap.ooc.sweep"):
+            for leaf in range(self._num_leaves):
+                for ci in range(-(-self._p_specs[leaf]["npages"] // R)):
+                    if (leaf, ci) in done:
+                        continue
+                    self._apply_chunk(leaf, ci)
+                    done.add((leaf, ci))
 
     # ------------------------------------------------------------------ step
 
@@ -454,25 +493,26 @@ class OOCTrainer:
         faults (bitwise-exact — stashed grads/scalars, chunk done-set) and
         raises ``OSError`` when the store stays down."""
         t0 = time.perf_counter()
-        for attempt in range(self.ocfg.max_step_retries + 1):
-            try:
-                if self._pending is None:
-                    self._prepare_update(batch)
-                self._apply_pending()
-                break
-            except OSError:
-                self.stats["io_errors"] += 1
-                if attempt >= self.ocfg.max_step_retries:
-                    raise
-                self.stats["step_retries"] += 1
-                self.drain_quarantine()
-        metrics = self._pending["metrics"]
-        self._pending = None
-        self.step_no += 1
-        if self.paged:
-            # The sweep mutated the params region; cached device layers in
-            # the grad-phase source are stale.
-            self.source.invalidate()
+        with spans.span("umap.ooc.step"):
+            for attempt in range(self.ocfg.max_step_retries + 1):
+                try:
+                    if self._pending is None:
+                        self._prepare_update(batch)
+                    self._apply_pending()
+                    break
+                except OSError:
+                    self.stats["io_errors"] += 1
+                    if attempt >= self.ocfg.max_step_retries:
+                        raise
+                    self.stats["step_retries"] += 1
+                    self.drain_quarantine()
+            metrics = self._pending["metrics"]
+            self._pending = None
+            self.step_no += 1
+            if self.paged:
+                # The sweep mutated the params region; cached device layers
+                # in the grad-phase source are stale.
+                self.source.invalidate()
         self.stats["steps"] += 1
         self.stats["last_step_s"] = time.perf_counter() - t0
         return metrics

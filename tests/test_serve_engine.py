@@ -523,3 +523,77 @@ def test_paging_degraded_probe_is_defensive(model):
     assert eng.paging_degraded() is False
     eng2 = ServeEngine(cfg, params, ecfg)          # no service wired at all
     assert eng2.paging_degraded() is False
+
+
+# ------------------------------------------------ program spans and names
+
+
+def test_engine_programs_are_named(model):
+    """The trace names the engine's programs ``jit_decode_step`` and
+    ``jit_prefill``, which the benchmark's readers find them by."""
+    cfg, params = model
+    eng = ServeEngine(cfg, params, EngineConfig(
+        max_batch=2, page_size=4, num_pages=16, max_pages_per_seq=8,
+        prefill_bucket=8))
+    lanes = jnp.zeros(2, jnp.int32)
+    text = eng._decode.lower(params, eng._caches, lanes, lanes).as_text()
+    assert "module @jit_decode_step " in text
+    batch = {"tokens": jnp.zeros((1, 8), jnp.int32)}
+    text = eng._prefill.lower(params, batch, M.init_cache(cfg, 1, 16)).as_text()
+    assert "module @jit_prefill " in text
+
+
+def test_traced_admissions_count_held_prefix_tokens(model, tmp_path):
+    """Under a profiler capture each engine step records its admission,
+    page work, table sync and decode; each prefill records, under its
+    request id, the positions it computed and those a shared prefix's
+    pages already held, which the engine's counters and collector hold
+    too."""
+    from repro.telemetry import spans
+    from repro.telemetry.collectors import ServeCollector
+
+    cfg, params = model
+    eng = ServeEngine(cfg, params, EngineConfig(
+        max_batch=4, page_size=4, num_pages=64, max_pages_per_seq=16,
+        prefill_bucket=8))
+    rng = np.random.default_rng(23)
+    prefix = rng.integers(1, cfg.vocab_size, 8).astype(np.int32)
+    eng.register_prefix(prefix)
+    prompts = [np.concatenate([prefix, rng.integers(1, cfg.vocab_size, 3)
+                               .astype(np.int32)]),
+               rng.integers(1, cfg.vocab_size, 6).astype(np.int32)]
+    for rid, p in enumerate(prompts):
+        eng.submit(Request(rid=rid, prompt=p, max_new_tokens=3))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.run_until_drained(max_steps=100)
+    finally:
+        jax.profiler.stop_trace()
+    recs = spans.session()
+    by_id = {r.span_id: r for r in recs}
+    assert sum(r.name == "umap.engine.decode" for r in recs) \
+        == eng.stats["steps"] > 0
+    for name in ("umap.engine.admit", "umap.engine.page_work",
+                 "umap.engine.sync_tables", "umap.engine.decode"):
+        assert {by_id[r.parent_id].name for r in recs if r.name == name} \
+            == {"umap.engine.step"}
+    pre = {r.rid: r for r in recs if r.name == "umap.engine.prefill"}
+    assert {by_id[r.parent_id].name for r in pre.values()} \
+        == {"umap.engine.admit"}
+    assert {r.parent_id for r in recs if r.name == "umap.engine.install"} \
+        == {r.span_id for r in pre.values()}
+    meta = cfg.num_meta_tokens
+    assert {rid: r.counts for rid, r in pre.items()} == {
+        0: {"tokens": len(prompts[0]) - 1 + meta,
+            "held_tokens": len(prefix) + meta},
+        1: {"tokens": len(prompts[1]) - 1 + meta, "held_tokens": 0}}
+    assert eng.stats["prefill_tokens"] == sum(
+        r.counts["tokens"] for r in pre.values())
+    assert eng.stats["prefill_tokens_held"] == len(prefix) + meta
+    fams = {f.name: f for f in ServeCollector(engine=eng).collect()}
+    assert fams["umap_serve_prefill_tokens_total"].samples[0][2] \
+        == eng.stats["prefill_tokens"]
+    assert fams["umap_serve_prefill_tokens_held_total"].samples[0][2] \
+        == len(prefix) + meta

@@ -495,6 +495,7 @@ class _FakeEngine:
                       "shared_pages_mapped": 9, "prefix_hits": 6,
                       "prefix_drops": 1, "peak_pages_used": 7,
                       "shed_requests": 2,
+                      "prefill_tokens": 640, "prefill_tokens_held": 512,
                       "per_tenant": {
                           "gold": {"prefills": 3, "evictions": 1,
                                    "requeues": 1, "admission_pauses": 0,
@@ -534,6 +535,7 @@ SERVE_ENGINE_FAMILIES = {
     "umap_serve_prefix_hits_total", "umap_serve_prefix_drops_total",
     "umap_serve_peak_pages_used", "umap_serve_tenants",
     "umap_serve_shed_total", "umap_serve_paging_degraded",
+    "umap_serve_prefill_tokens_total", "umap_serve_prefill_tokens_held_total",
 }
 SERVE_TENANT_FAMILIES = {
     "umap_serve_tenant_prefills_total", "umap_serve_tenant_evictions_total",
@@ -567,6 +569,12 @@ class TestServeCollector:
         assert fams["umap_serve_steps_total"].samples[0][2] == 10
         assert fams["umap_serve_active_requests"].samples[0][2] == 2
         assert fams["umap_serve_pool_occupancy_ratio"].samples[0][2] == 0.5
+
+    def test_prefill_token_counters(self):
+        fams = families_of(ServeCollector(engine=_FakeEngine(), label="e"))
+        assert fams["umap_serve_prefill_tokens_total"].samples[0][2] == 640
+        assert fams["umap_serve_prefill_tokens_held_total"] \
+            .samples[0][2] == 512
 
     def test_per_tenant_labels_match_stats(self):
         """Every per-tenant family carries one sample per tenant, labeled

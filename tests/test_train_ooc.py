@@ -393,6 +393,55 @@ class TestTrainCollector:
         finally:
             tr.close()
 
+    def test_transfer_counters_match_the_step_spans(self, tmp_path):
+        """The trainer counts every host<->device copy of a step in its
+        stats, which the collector exports; under a profiler capture the
+        step records its grad phase and sweep inside it, and transfer spans
+        carrying the same counts."""
+        from repro.telemetry import spans
+        from repro.telemetry.collectors import TrainCollector
+
+        tr = _make(paged=True, ocfg_kw=_paged_kw(PAGE, 2))
+        try:
+            keys = ("h2d_copies", "h2d_bytes", "d2h_copies", "d2h_bytes")
+            before = {k: tr.stats[k] for k in keys}
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                tr.step(_batches(1)[0])
+            finally:
+                jax.profiler.stop_trace()
+            recs = spans.session()
+            by_id = {r.span_id: r for r in recs}
+            (step,) = [r for r in recs if r.name == "umap.ooc.step"]
+            for name in ("umap.ooc.grad", "umap.ooc.sweep"):
+                (r,) = [r for r in recs if r.name == name]
+                assert r.parent_id == step.span_id
+            assert {by_id[r.parent_id].name for r in recs
+                    if r.name == "umap.transfer.d2h"} \
+                == {"umap.ooc.grad", "umap.ooc.page_update"}
+            for d in ("h2d", "d2h"):
+                mine = [r.counts for r in recs
+                        if r.name == f"umap.transfer.{d}"]
+                for what in ("copies", "bytes"):
+                    k = f"{d}_{what}"
+                    assert tr.stats[k] - before[k] \
+                        == sum(c[what] for c in mine) > 0
+            # the parameter source counts its own page fetches
+            assert 0 < tr.source.h2d_copies < tr.stats["h2d_copies"]
+            by_name = {f.name: f for f in TrainCollector(trainer=tr).collect()}
+            for k in keys:
+                assert by_name[f"umap_train_{k}_total"].samples[0][2] \
+                    == tr.stats[k]
+            # a sweep page moves its params, grads and moments in and its
+            # results out: at least 5 copies per parameter page
+            pages = sum(s["npages"] for s in tr._p_specs)
+            assert tr.stats["h2d_copies"] + tr.stats["d2h_copies"] \
+                >= 5 * pages
+        finally:
+            tr.close()
+
     def test_empty_without_trainer(self):
         from repro.telemetry.collectors import TrainCollector
 
