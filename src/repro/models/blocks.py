@@ -6,7 +6,9 @@ Each block kind provides:
 
 ``ctx`` carries the mode ("train" | "prefill" | "decode"), positions, the
 per-layer cache slice, and (for cross-attention) the encoder memory.  Blocks
-never see the layer stack — transformer.py scans them.
+never see the layer stack — transformer.py scans them — except a paged
+decode cache's pools, which are the segment's whole stack with this layer's
+index beside them, so that the token is written where the pool lies.
 """
 
 from __future__ import annotations
@@ -140,10 +142,10 @@ def attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
         o = attention(q, ke, ve, spec, q_pos, q_pos)
         new_cache = _write_prefill_cache(ctx.cache, k, v, q_pos, window)
     elif ctx.cache is not None and "k_pool" in ctx.cache:
-        # paged decode: KV lives in a page pool; the page table (host-managed
-        # by kvcache/allocator) maps logical pages -> pool slots.  The XLA
-        # path gathers pages; on TPU kernels/paged_attention reads through
-        # the table in-kernel (ops.py selects by backend).
+        # paged decode: KV lives in the segment's stacked page pool, carried
+        # through the layer scan; the page table (host-managed by
+        # kvcache/allocator) maps logical pages -> pool slots, and the
+        # table's pages are gathered for attention.
         assert s == 1
         new_cache = _write_paged_cache(ctx.cache, k, v)
         kc, vc, kpos, cur = _gather_paged(new_cache)
@@ -209,23 +211,35 @@ def _write_prefill_cache(cache, k, v, positions, window):
 
 
 def _write_paged_cache(cache, k, v):
-    """Append one token through the page table.
+    """Append one token through the page table, in place.
 
-    cache: {"k_pool"/"v_pool": [P, ps, KVH, D], "table": [B, maxp],
-            "len": [B]}.  The new token for sequence b goes to physical page
-    table[b, len[b] // ps], slot len[b] % ps.  Page *allocation* happened
+    cache: {"k_pool"/"v_pool": the segment's stacked [count, P, ps, KVH, D]
+    pools, "layer": this layer's index into them, "table": [B, maxp],
+    "len": [B]}.  The new token for sequence b goes to physical page
+    table[b, len[b] // ps], slot len[b] % ps of pool[layer]; the pools are
+    the decode scan's carry, so the scatter updates them where they lie
+    (transformer._scan_segment_decode).  Page *allocation* happened
     host-side (kvcache/allocator) before this step.
+
+    Each (page, slot, head) row of D is written on its own, which leaves
+    the pool's layout to the gather: for a whole [KVH, D] token the TPU
+    compiler lays the pool out with KVH and D minor, and where the gather
+    wants another (KVH 3, D 64) it then copies the whole stacked pool once
+    a layer.
     """
-    ps = cache["k_pool"].shape[1]
+    layer = cache["layer"]
+    ps = cache["k_pool"].shape[2]
     lens = cache["len"]                                   # [B]
     bi = jnp.arange(lens.shape[0])
-    pages = cache["table"][bi, lens // ps]                # [B]
-    slots = lens % ps
+    pages = cache["table"][bi, lens // ps][:, None]       # [B, 1]
+    slots = (lens % ps)[:, None]
+    heads = jnp.arange(k.shape[2])[None, :]               # [1, KVH]
     return {
-        "k_pool": cache["k_pool"].at[pages, slots].set(
+        "k_pool": cache["k_pool"].at[layer, pages, slots, heads].set(
             k[:, 0].astype(cache["k_pool"].dtype)),
-        "v_pool": cache["v_pool"].at[pages, slots].set(
+        "v_pool": cache["v_pool"].at[layer, pages, slots, heads].set(
             v[:, 0].astype(cache["v_pool"].dtype)),
+        "layer": layer,
         "table": cache["table"],
         "len": lens + 1,
     }
@@ -234,13 +248,17 @@ def _write_paged_cache(cache, k, v):
 def _gather_paged(cache):
     """XLA read path: gather table pages -> contiguous [B, S, KVH, D].
 
-    (On TPU the Pallas paged_attention kernel replaces gather+attend; this
-    path is the portable fallback and the CPU-test oracle.)
+    The layer index is folded into the gather (``pool[layer, table]``), so
+    only the table's pages are read and no per-layer pool is materialized.
+    The engine decodes through this gather on every backend; the Pallas
+    kernel in kernels/paged_attention lays its pool out [P, KVH, ps, D]
+    and is not on this path.
     """
     b, maxp = cache["table"].shape
-    ps = cache["k_pool"].shape[1]
-    kc = cache["k_pool"][cache["table"]]                  # [B, maxp, ps, KVH, D]
-    vc = cache["v_pool"][cache["table"]]
+    ps = cache["k_pool"].shape[2]
+    layer = cache["layer"]
+    kc = cache["k_pool"][layer, cache["table"]]           # [B, maxp, ps, KVH, D]
+    vc = cache["v_pool"][layer, cache["table"]]
     kc = kc.reshape(b, maxp * ps, *kc.shape[3:])
     vc = vc.reshape(b, maxp * ps, *vc.shape[3:])
     lens = cache["len"]                                   # post-write lengths

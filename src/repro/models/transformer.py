@@ -348,12 +348,32 @@ def decode_step(cfg: ModelConfig, params: dict, cache: list,
     return lm_logits(cfg, params, x[:, 0]), new_caches
 
 
+_POOL_KEYS = ("k_pool", "v_pool")
+
+
 def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
                          ctx: BlockCtx, cache_seg):
-    """Decode-mode scan; handles cached cross-attention KV for enc-dec."""
+    """Decode-mode scan; handles cached cross-attention KV for enc-dec.
 
-    def body(x, xs):
+    A paged cache's stacked ``k_pool``/``v_pool`` [count, P, ps, KVH, D]
+    ride in the scan carry with the layer index: each layer writes its
+    token into them in place and gathers its pages from them directly
+    (blocks._write_paged_cache / _gather_paged), so no per-layer pool is
+    sliced out and no fresh stacked pool is written back, and a caller
+    that donates the caches gets the pools updated where they lie.
+    Everything else (dense and ring caches, page tables, lengths,
+    recurrent state) scans as ``xs``/``ys``.
+    """
+    pools = {}
+    if isinstance(cache_seg, dict) and "k_pool" in cache_seg:
+        pools = {k: cache_seg[k] for k in _POOL_KEYS}
+        cache_seg = {k: v for k, v in cache_seg.items() if k not in _POOL_KEYS}
+
+    def body(carry, xs):
+        x, layer, pools = carry
         layer_p, cache_l = xs
+        if pools:
+            cache_l = {**cache_l, **pools, "layer": layer}
         lctx = BlockCtx(mode="decode", positions=ctx.positions,
                         cache=cache_l, cur_pos=ctx.cur_pos)
         if seg.kind == "xdecoder":
@@ -362,9 +382,16 @@ def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
             x, new_cache, _ = block_apply(cfg, seg, layer_p, x, lctx)
             if isinstance(cache_l, dict) and "xk" in cache_l:
                 new_cache["xk"], new_cache["xv"] = cache_l["xk"], cache_l["xv"]
-        return x, new_cache
+        if pools:
+            pools = {k: new_cache[k] for k in _POOL_KEYS}
+            new_cache = {k: v for k, v in new_cache.items()
+                         if k not in _POOL_KEYS + ("layer",)}
+        return (x, layer + 1, pools), new_cache
 
-    x, new_caches = jax.lax.scan(body, x, (seg_params, cache_seg))
+    (x, _, pools), new_caches = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.int32), pools), (seg_params, cache_seg))
+    if pools:
+        new_caches = {**new_caches, **pools}
     return x, None, new_caches
 
 

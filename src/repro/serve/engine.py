@@ -10,9 +10,10 @@ paper's application-hints thesis applied to serving (DESIGN.md §16):
                   (deadline headroom, not binary occupancy), tenant-weighted
                   victim selection under pool pressure, straggler requeue
                   with bounded restarts;
-  * device side — one jitted ``decode_step`` whose KV pages are jit inputs
-                  ({k_pool, v_pool, table, len} per attention segment) and a
-                  jitted bucketed ``prefill``.
+  * device side — one jitted ``decode_step`` (``decode_program``) that
+                  takes the caches ({k_pool, v_pool, table, len} per
+                  attention segment) donated and writes each new token into
+                  the pools in place, and a jitted bucketed ``prefill``.
 
 Decode batches are fixed-width (max_batch) with empty lanes masked, so one
 compiled executable serves any active-set composition — the continuous
@@ -182,15 +183,12 @@ class ServeEngine:
                       "prefill_tokens": 0, "prefill_tokens_held": 0,
                       "per_tenant": {}}
         self._caches = self._init_caches()
+        self._decode = decode_program(cfg)
 
-        # named functions, so that the trace names the programs
-        def decode_step(params, caches, tokens, cur_pos):
-            return T.decode_step(cfg, params, caches, tokens, cur_pos)
-
+        # a named function, so that the trace names the program
         def prefill(params, batch, cache):
             return T.prefill(cfg, params, batch, cache)
 
-        self._decode = jax.jit(decode_step)
         self._prefill = jax.jit(prefill)   # one per bucket
 
     def register_telemetry(self, registry=None, label=None) -> str:
@@ -864,6 +862,23 @@ class ServeEngine:
 
 
 # ---------------------------------------------------------------- helpers
+
+
+def decode_program(cfg: ModelConfig):
+    """The engine's decode program: ``T.decode_step`` jitted with the
+    caches (argument 1) donated.
+
+    The decode scan carries each segment's stacked KV pools and writes the
+    step's tokens into them in place, so with donation the pools come back
+    in the buffers they went in: no pool-sized copy a step.  The caller
+    must not read the caches it passed after the call, and must pass no
+    buffer twice.  The function is named so that the trace names the
+    program ``jit_decode_step``.
+    """
+    def decode_step(params, caches, tokens, cur_pos):
+        return T.decode_step(cfg, params, caches, tokens, cur_pos)
+
+    return jax.jit(decode_step, donate_argnums=(1,))
 
 
 def _install_pages(cache, k, v, pages, lane, page_size, max_pages,

@@ -597,3 +597,89 @@ def test_traced_admissions_count_held_prefix_tokens(model, tmp_path):
         == eng.stats["prefill_tokens"]
     assert fams["umap_serve_prefill_tokens_held_total"].samples[0][2] \
         == len(prefix) + meta
+
+
+# ------------------------------------------------- in-place paged decode
+
+
+def _xs_ys_decode_step(cfg, params, caches, tokens, cur_pos):
+    """The decode step with each layer's pool scanned through ``xs`` and a
+    new stacked pool written back through ``ys``: the path the engine took
+    before the pools rode in the scan carry, kept here as the oracle."""
+    from repro.models import transformer as T
+    from repro.models.blocks import BlockCtx, block_apply
+    from repro.models.layers import rms_norm
+
+    params = T.cast_params(cfg, params)
+    x = T.embed_tokens(cfg, params, tokens[:, None])
+    new_caches = []
+    for seg, seg_p, cache in zip(cfg.decoder_plan(), params["segments"],
+                                 caches):
+        def body(x, xs):
+            layer_p, c = xs
+            # this layer's pool as a stack of one, written at layer 0
+            c = {**c, "k_pool": c["k_pool"][None], "v_pool": c["v_pool"][None],
+                 "layer": jnp.zeros((), jnp.int32)}
+            x, nc, _ = block_apply(cfg, seg, layer_p, x, BlockCtx(
+                mode="decode", positions=cur_pos[:, None], cache=c,
+                cur_pos=cur_pos))
+            nc = {k: v for k, v in nc.items() if k != "layer"}
+            return x, {**nc, "k_pool": nc["k_pool"][0],
+                       "v_pool": nc["v_pool"][0]}
+
+        x, nc = jax.lax.scan(body, x, (seg_p, cache))
+        new_caches.append(nc)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return T.lm_logits(cfg, params, x[:, 0]), new_caches
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "hymba-1.5b"])
+def test_in_place_decode_matches_xs_ys_path(arch):
+    """The engine's decode program writes the pools in place through its
+    donated caches (a hybrid segment's recurrent state still scans per
+    layer).  Over a run with a shared prefix, COW of the shared boundary
+    page (at prefill and at a decode write) and requeues on pool
+    exhaustion, it serves token for token what the same engine serves
+    with the xs/ys decode oracle, and every call deletes the caches it
+    was passed."""
+    cfg = get_smoke_config(arch)
+    params = M.init_params(cfg, jax.random.key(0))
+    rng = np.random.default_rng(31)
+    # 10 % 4 != 0: the prefix's last page is a shared boundary page
+    prefix = rng.integers(1, cfg.vocab_size, 10).astype(np.int32)
+    prompts = [prefix if i % 2 else np.concatenate(
+        [prefix, rng.integers(1, cfg.vocab_size, 3).astype(np.int32)])
+        for i in range(6)]
+
+    def run(decode=None):
+        eng = ServeEngine(cfg, params, EngineConfig(
+            max_batch=3, page_size=4, num_pages=16, max_pages_per_seq=8,
+            prefill_bucket=8))
+        deleted = []
+        if decode is None:
+            donated = eng._decode
+
+            def decode(params, caches, tokens, cur_pos):
+                out = donated(params, caches, tokens, cur_pos)
+                deleted.append(all(a.is_deleted()
+                                   for a in jax.tree.leaves(caches)))
+                return out
+        eng._decode = decode
+        eng.register_prefix(prefix)
+        reqs = [Request(rid=i, prompt=p.copy(), max_new_tokens=9)
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained(max_steps=500)
+        assert_none_lost(eng, reqs)
+        return eng, {r.rid: r.generated for r in reqs}, deleted
+
+    eng, served, deleted = run()
+    oracle_eng, oracle, _ = run(jax.jit(
+        lambda p, c, t, pos: _xs_ys_decode_step(cfg, p, c, t, pos)))
+    assert served == oracle
+    assert deleted and all(deleted), "a decode call left its caches alive"
+    for key in ("steps", "requeues", "cow_copies", "prefix_hits"):
+        assert eng.stats[key] == oracle_eng.stats[key], key
+    assert eng.stats["requeues"] >= 1, "scenario must exhaust the pool"
+    assert eng.stats["cow_copies"] >= 2, "both COW paths must run"

@@ -11,6 +11,7 @@ imports every test file.
 """
 
 import importlib.util
+import re
 from functools import partial
 from pathlib import Path
 from types import SimpleNamespace
@@ -26,7 +27,7 @@ from repro.kernels.page_gather import ops as page_ops
 from repro.kernels.paged_attention import kernel as attn_kernel
 from repro.kernels.paged_attention import ops as attn_ops
 from repro.models import transformer as T
-from repro.serve.engine import EngineConfig, ServeEngine
+from repro.serve.engine import EngineConfig, ServeEngine, decode_program
 
 
 @pytest.fixture(scope="module")
@@ -120,6 +121,66 @@ def test_engine_decode_step_compiles_for_v5e(one_chip, smollm):
         _sds((b,), jnp.int32, one_chip)).compile()
     logits = compiled.out_info[0]
     assert logits.shape == (b, cfg.padded_vocab)
+
+
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+_INSTR = re.compile(r"\s+(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _pool_instructions(hlo: str, pool_shape):
+    """(computation, opcode, op_name) of every instruction of the optimized
+    HLO whose array has the stacked pool's shape or one layer's, unit
+    dimensions aside, other than tuple plumbing."""
+    shapes = {tuple(d for d in pool_shape if d != 1),
+              tuple(d for d in pool_shape[1:] if d != 1)}
+    found, comp = [], None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace():
+            comp = "ENTRY" if line.startswith("ENTRY") else line.split()[0]
+            continue
+        m = _INSTR.match(line)
+        if not m or m.group(2) in _PLUMBING:
+            continue
+        dims = tuple(int(d) for d in m.group(1).split(",") if d and d != "1")
+        if dims in shapes:
+            op = re.search(r'op_name="([^"]*)"', line)
+            found.append((comp, m.group(2), op.group(1) if op else ""))
+    return found
+
+
+# Qwen2-1.5B's widths are the serve cells'.  smollm-135m's pools (D 64 and
+# 512 pages) take a default device layout with pages minor, which the page
+# gather cannot read in place, so there the program relayouts each pool
+# once into the layer loop and once out of it, and nowhere else.
+@pytest.mark.parametrize("arch,relayouts", [("smollm-135m", 4),
+                                            ("qwen2-1.5b", 0)])
+def test_engine_decode_writes_pools_in_place_on_v5e(one_chip, arch,
+                                                    relayouts):
+    """The engine's decode program (caches donated) gets the stacked KV
+    pools back in the buffers they came in, and no instruction copies,
+    slices or rebuilds a pool: inside the layer loop a pool-shaped array
+    is only ever the in-place write of the step's tokens."""
+    cfg = get_config(arch).replace(head_pad_multiple=1)
+    ecfg = EngineConfig(max_batch=8, page_size=16, num_pages=512,
+                        max_pages_per_seq=32)
+    params = jax.eval_shape(partial(T.init_params, cfg), jax.random.key(0))
+    engine = SimpleNamespace(cfg=cfg, ecfg=ecfg, plan=cfg.decoder_plan())
+    caches = jax.eval_shape(partial(ServeEngine._init_caches, engine))
+    b = ecfg.max_batch
+    compiled = decode_program(cfg).lower(
+        _on(params, one_chip), _on(caches, one_chip),
+        _sds((b,), jnp.int32, one_chip),
+        _sds((b,), jnp.int32, one_chip)).compile()
+    pools = [c[k] for c in caches for k in ("k_pool", "v_pool")]
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    found = _pool_instructions(compiled.as_text(), pools[0].shape)
+    writes = [f for f in found if f[1] in ("scatter", "fusion")
+              and f[2].endswith("/scatter")]
+    assert writes, "the tokens' write into the pools was not found"
+    copies = [f for f in found if f[:2] == ("ENTRY", "copy")]
+    assert len(copies) == relayouts, copies
+    assert not [f for f in found if f not in writes + copies]
 
 
 def test_engine_prefill_compiles_for_v5e(one_chip, smollm):
