@@ -50,7 +50,21 @@ class ModelConfig:
     num_experts: int = 0
     top_k: int = 0
     moe_sharding: str = "ep"              # "ep" | "tp"
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25         # training dispatch only (DESIGN.md §19)
+    moe_d_ff: int = 0                     # expert width; 0 -> d_ff
+    num_shared_experts: int = 0           # always-on experts, one SwiGLU
+    first_k_dense: int = 0                # leading dense layers (DeepSeek)
+    norm_topk_prob: bool = True           # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0
+
+    # --- latent attention (MLA, DeepSeek-V2 §2.1); kv_lora_rank > 0 -------
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # DeepSeek yarn: (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale, mscale_all_dim)
+    rope_yarn: Optional[Tuple[float, int, float, float, float, float]] = None
 
     # --- SSM / hybrid -------------------------------------------------------
     ssm_state: int = 0
@@ -93,6 +107,14 @@ class ModelConfig:
     @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab_size, self.vocab_pad_multiple)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def dt_rank_actual(self) -> int:
@@ -149,6 +171,14 @@ class ModelConfig:
                 "hybrid": "hymba"}.get(self.family)
         if kind is None:
             raise ValueError(f"no decoder plan for family {self.family!r}")
+        if self.first_k_dense:
+            if kind != "moe" or self.global_layers:
+                raise ValueError("first_k_dense needs an MoE family without "
+                                 "global layers")
+            k = self.first_k_dense
+            return [Segment("dense", k, 0, window=self.sliding_window),
+                    Segment("moe", self.num_layers - k, k,
+                            window=self.sliding_window)]
         if not self.global_layers:
             return [Segment(kind, self.num_layers, 0, window=self.sliding_window)]
         # split around full-attention layers (hymba)

@@ -25,7 +25,12 @@ _MODULES = {
     "xlstm-1.3b": "xlstm_1p3b",
 }
 
+#: the assigned architectures: the dry-run matrix (DESIGN.md §5)
 ARCH_IDS = tuple(_MODULES)
+
+# served on one chip through the engine, outside the dry-run matrix
+# (DESIGN.md §19)
+_MODULES["deepseek-v2-lite"] = "deepseek_v2_lite"
 
 
 def _mod(arch: str):

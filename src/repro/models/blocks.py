@@ -1,5 +1,9 @@
 """Decoder/encoder blocks for every segment kind, cache-aware.
 
+Attention is grouped-query (per-head K and V) or, where the config sets
+``kv_lora_rank``, multi-head latent attention (MLA): its cache holds one
+latent row per token, and ``pool_rows`` gives each geometry's pools.
+
 Each block kind provides:
   *_specs(cfg)                     -> pytree of ParamSpec (one layer)
   apply(cfg, seg, p, x, ctx)      -> (x, new_cache_layer, aux)
@@ -20,9 +24,11 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, Segment
+from ..distributed.sharding import active_mesh
 from ..distributed.sharding import with_logical_constraint as wlc
 from .common import ParamSpec, fan_in_init, normal_init, ones_init, zeros_init
 from .layers import (
+    NEG_INF,
     AttnSpec,
     apply_rope,
     attention,
@@ -32,8 +38,15 @@ from .layers import (
     make_qh_to_kv_map,
     rms_norm,
     swiglu,
+    yarn_mscale,
 )
-from .moe import moe_forward, moe_param_specs
+from .moe import (
+    MOE_STATS,
+    moe_dropless,
+    moe_forward,
+    moe_param_specs,
+    shared_expert_specs,
+)
 from .ssm import (
     mamba_decode,
     mamba_forward,
@@ -140,7 +153,8 @@ def attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
                         chunk_size=cfg.attn_chunk, causal_skip=cfg.causal_skip)
         ke, ve = expand_kv(k, qh_map), expand_kv(v, qh_map)
         o = attention(q, ke, ve, spec, q_pos, q_pos)
-        new_cache = _write_prefill_cache(ctx.cache, k, v, q_pos, window)
+        new_cache = _write_prefill_cache(ctx.cache, {"k": k, "v": v}, q_pos,
+                                         window)
     elif ctx.cache is not None and "k_pool" in ctx.cache:
         # paged decode: KV lives in the segment's stacked page pool, carried
         # through the layer scan; the page table (host-managed by
@@ -159,7 +173,8 @@ def attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
     else:  # decode: one token against the cache
         assert s == 1
         cache = ctx.cache
-        new_cache = _write_decode_cache(cache, k, v, ctx.cur_pos, window)
+        new_cache = _write_decode_cache(cache, {"k": k, "v": v}, ctx.cur_pos,
+                                        window)
         kc, vc = new_cache["k"], new_cache["v"]
         # Sequence-sharded decode (flash-decoding over the model axis):
         # the cache shards on kv_seq; q and o stay replicated over "model",
@@ -185,29 +200,21 @@ def attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
     return (o.reshape(b, s, -1) @ p["wo"]), new_cache
 
 
-def _write_prefill_cache(cache, k, v, positions, window):
-    """Install prefilled KV into a (possibly ring) cache."""
-    S_cache = cache["k"].shape[1]
+def _write_prefill_cache(cache, rows, positions, window):
+    """Install prefilled rows ({name: [B, S, *row]}: K and V, or MLA's
+    latent rows) into a (possibly ring) cache."""
+    S_cache = cache["pos"].shape[1]
     b, s = positions.shape
     if window is not None and S_cache < s:
         # ring cache: keep the last S_cache tokens at slot = pos % S_cache
-        k_tail = k[:, -S_cache:]
-        v_tail = v[:, -S_cache:]
-        pos_tail = positions[:, -S_cache:]
-        slots = pos_tail % S_cache                      # [b, S_cache]
-        bi = jnp.arange(b)[:, None]
-        return {
-            "k": cache["k"].at[bi, slots].set(k_tail.astype(cache["k"].dtype)),
-            "v": cache["v"].at[bi, slots].set(v_tail.astype(cache["v"].dtype)),
-            "pos": cache["pos"].at[bi, slots].set(pos_tail),
-        }
+        rows = {n: x[:, -S_cache:] for n, x in rows.items()}
+        positions = positions[:, -S_cache:]
     bi = jnp.arange(b)[:, None]
     slots = positions % S_cache if window is not None else positions
-    return {
-        "k": cache["k"].at[bi, slots].set(k.astype(cache["k"].dtype)),
-        "v": cache["v"].at[bi, slots].set(v.astype(cache["v"].dtype)),
-        "pos": cache["pos"].at[bi, slots].set(positions),
-    }
+    out = {n: cache[n].at[bi, slots].set(x.astype(cache[n].dtype))
+           for n, x in rows.items()}
+    out["pos"] = cache["pos"].at[bi, slots].set(positions)
+    return out
 
 
 def _write_paged_cache(cache, k, v):
@@ -267,28 +274,194 @@ def _gather_paged(cache):
     return kc, vc, kpos, lens - 1                          # cur_pos = len-1
 
 
-def _write_decode_cache(cache, k, v, cur_pos, window):
-    S_cache = cache["k"].shape[1]
-    b = k.shape[0]
+def _write_decode_cache(cache, rows, cur_pos, window):
+    """Write one token's rows ({name: [B, 1, *row]}) at ``cur_pos`` (its
+    ring slot in a windowed cache)."""
+    S_cache = cache["pos"].shape[1]
     slots = (cur_pos % S_cache) if window is not None else cur_pos  # [b]
-    bi = jnp.arange(b)
-    return {
-        "k": cache["k"].at[bi, slots].set(k[:, 0].astype(cache["k"].dtype)),
-        "v": cache["v"].at[bi, slots].set(v[:, 0].astype(cache["v"].dtype)),
-        "pos": cache["pos"].at[bi, slots].set(cur_pos),
-    }
+    bi = jnp.arange(cur_pos.shape[0])
+    out = {n: cache[n].at[bi, slots].set(x[:, 0].astype(cache[n].dtype))
+           for n, x in rows.items()}
+    out["pos"] = cache["pos"].at[bi, slots].set(cur_pos)
+    return out
+
+
+def pool_rows(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
+    """What an attention layer caches per token: {name: row shape}.  The
+    contiguous cache holds ``name`` [B, S, *row] and the engine's paged
+    cache ``<name>_pool`` [count, pages, page_size, *row].  GQA caches
+    per-head keys and values; MLA one row of the normalised latent and the
+    roped shared key (kv_lora_rank + qk_rope_head_dim, zero-padded to
+    ``mla_row_width``)."""
+    if cfg.mla:
+        return {"ckv": (mla_row_width(cfg),)}
+    row = (cfg.num_kv_heads, cfg.head_dim)
+    return {"k": row, "v": row}
+
+
+#: MLA's cached row is padded with zeros to a multiple of the TPU's lane
+#: width: for a 576-wide row (DeepSeek-V2) the device's default layout of
+#: the stacked pool puts pages minor, and the decode step then relayouts
+#: the whole pool into its layer loop and out of it (AOT for a v5e); at 640
+#: the pool keeps rows minor, which the tiled layout pads 576 to anyway.
+MLA_ROW_MULTIPLE = 128
+
+
+def mla_row_width(cfg: ModelConfig) -> int:
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+             // MLA_ROW_MULTIPLE) * MLA_ROW_MULTIPLE
 
 
 def attn_cache_init(cfg: ModelConfig, seg: Segment, batch: int, seq_len: int,
-                    dtype) -> Dict[str, jax.Array]:
-    """Per-LAYER cache slice geometry (stacked by the caller)."""
-    S = seq_len if seg.window is None else min(seq_len, seg.window)
-    kvh = cfg.num_kv_heads
+                    dtype, ring: bool = True) -> Dict[str, jax.Array]:
+    """Per-LAYER cache slice geometry (stacked by the caller).  A windowed
+    segment gets a ring of ``window`` slots unless ``ring`` is False (the
+    engine's prefill keeps every prompt position for its pool pages)."""
+    S = seq_len if seg.window is None or not ring else min(seq_len, seg.window)
+    out = {n: jnp.zeros((batch, S) + row, dtype)
+           for n, row in pool_rows(cfg).items()}
+    out["pos"] = jnp.full((batch, S), -1, jnp.int32)
+    return out
+
+
+# ----------------------------------------------------- latent attention (MLA)
+
+
+def mla_param_specs(cfg: ModelConfig) -> dict:
+    """DeepSeek-V2 MLA without q LoRA; every matrix [in, out]."""
+    H, E, r = cfg.num_heads, cfg.d_model, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     return {
-        "k": jnp.zeros((batch, S, kvh, cfg.head_dim), dtype),
-        "v": jnp.zeros((batch, S, kvh, cfg.head_dim), dtype),
-        "pos": jnp.full((batch, S), -1, jnp.int32),
+        "wq": ParamSpec((E, H * (dn + dr)), ("embed", "heads"), fan_in_init),
+        "wkv_a": ParamSpec((E, r + dr), ("embed", None), fan_in_init),
+        "kv_norm": ParamSpec((r,), (None,), ones_init),
+        "wkv_b": ParamSpec((r, H * (dn + dv)), (None, "heads"), fan_in_init),
+        "wo": ParamSpec((H * dv, E), ("heads", "embed"), fan_in_init),
     }
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(nope + rope dims), times yarn's mscale(mscale_all_dim)^2."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_yarn is not None and cfg.rope_yarn[5]:
+        scale *= yarn_mscale(cfg.rope_yarn[0], cfg.rope_yarn[5]) ** 2
+    return scale
+
+
+def mla_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
+              ctx: BlockCtx):
+    """MLA sublayer -> (out [B,S,d], new_cache).
+
+    Train and prefill decompress each head's keys and values from the
+    latent (k = [c W_uk, k_pe], v = c W_uv) and attend causally.  Decode is
+    absorbed: the query is taken into the latent space (q_nope W_uk^T), so
+    scores and the weighted sum run over the cached latent rows directly,
+    and only the head outputs are decompressed (W_uv).  The cache holds,
+    per token, the normalised latent and the roped shared key (one row of
+    ``kv_lora_rank + qk_rope_head_dim``, zero-padded to ``mla_row_width``)."""
+    b, s, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = (x @ p["wq"]).reshape(b, s, H, dn + dr)
+    kv_a = x @ p["wkv_a"]
+    c = rms_norm(kv_a[..., :r], p["kv_norm"], cfg.norm_eps)
+    q_pe = apply_rope(q[..., dn:], ctx.positions, cfg.rope_theta,
+                      yarn=cfg.rope_yarn)
+    k_pe = apply_rope(kv_a[..., None, r:], ctx.positions, cfg.rope_theta,
+                      yarn=cfg.rope_yarn)                  # [B,S,1,dr]
+    pad = mla_row_width(cfg) - r - dr
+    row = jnp.concatenate([c, k_pe[:, :, 0],
+                           jnp.zeros((b, s, pad), c.dtype)], axis=-1)
+    scale = mla_softmax_scale(cfg)
+    q_pos = _rope_q_positions(cfg, ctx.positions)
+
+    if ctx.mode == "decode":
+        assert s == 1
+        if "ckv_pool" in ctx.cache:
+            new_cache = _write_paged_rows(ctx.cache, row)
+            rows, kpos, cur = _gather_paged_rows(new_cache)
+        else:
+            new_cache = _write_decode_cache(ctx.cache, {"ckv": row},
+                                            ctx.cur_pos, seg.window)
+            rows, kpos, cur = new_cache["ckv"], new_cache["pos"], ctx.cur_pos
+        o = _mla_absorbed(cfg, p, q[:, 0, :, :dn], q_pe[:, 0], rows, kpos,
+                          cur, scale, seg.window)
+        return o.reshape(b, s, H * dv) @ p["wo"], new_cache
+
+    kv = (c @ p["wkv_b"]).reshape(b, s, H, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, H, dr))], axis=-1)
+    qf = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    spec = AttnSpec(causal=True, window=seg.window, impl="auto",
+                    chunk_size=cfg.attn_chunk, scale=scale)
+    o = attention(qf, k, kv[..., dn:], spec, q_pos, q_pos)
+    new_cache = None
+    if ctx.mode == "prefill" and ctx.cache is not None:
+        new_cache = _write_prefill_cache(ctx.cache, {"ckv": row}, q_pos,
+                                         seg.window)
+    o = wlc(o, "batch", "seq", "heads", "head_dim")
+    return o.reshape(b, s, H * dv) @ p["wo"], new_cache
+
+
+def _mla_absorbed(cfg, p, q_nope, q_pe, rows, kpos, cur_pos, scale, window):
+    """One query per sequence against cached latent rows [B, S, width]
+    (c, k_pe, zero padding): score = [q_nope W_uk^T, q_pe, 0] . row,
+    out = (sum p c) W_uv -> [B,H,dv].
+    Products take the cache's dtype and accumulate in float32; the
+    softmax is float32."""
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn = cfg.qk_nope_head_dim
+    w = p["wkv_b"].reshape(r, H, -1)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w[..., :dn],
+                       preferred_element_type=jnp.float32)
+    pad = rows.shape[-1] - r - q_pe.shape[-1]
+    qc = jnp.concatenate([q_lat, q_pe.astype(jnp.float32),
+                          jnp.zeros(q_lat.shape[:2] + (pad,), jnp.float32)], -1)
+    sc = jnp.einsum("bhc,bkc->bhk", qc.astype(rows.dtype), rows,
+                    preferred_element_type=jnp.float32) * scale
+    dlt = cur_pos[:, None] - kpos
+    ok = (kpos >= 0) & (dlt >= 0)
+    if window is not None:
+        ok &= dlt < window
+    sc = jnp.where(ok[:, None, :], sc, NEG_INF)
+    pr = jax.nn.softmax(sc, axis=-1)
+    o_lat = jnp.einsum("bhk,bkc->bhc", pr.astype(rows.dtype), rows,
+                       preferred_element_type=jnp.float32)[..., :r]
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(w.dtype), w[..., dn:],
+                   preferred_element_type=jnp.float32)
+    return o.astype(q_nope.dtype)
+
+
+def _write_paged_rows(cache, row):
+    """Append one latent row per sequence through the page table into the
+    segment's stacked ``ckv_pool`` [count, P, ps, width], in place (the
+    layout and carry of ``_write_paged_cache``)."""
+    layer = cache["layer"]
+    ps = cache["ckv_pool"].shape[2]
+    lens = cache["len"]
+    bi = jnp.arange(lens.shape[0])
+    pages = cache["table"][bi, lens // ps]
+    return {
+        "ckv_pool": cache["ckv_pool"].at[layer, pages, lens % ps].set(
+            row[:, 0].astype(cache["ckv_pool"].dtype)),
+        "layer": layer, "table": cache["table"], "len": lens + 1,
+    }
+
+
+def _gather_paged_rows(cache):
+    """The table's latent rows of this layer -> [B, maxp * ps, width]."""
+    b, maxp = cache["table"].shape
+    ps = cache["ckv_pool"].shape[2]
+    rows = cache["ckv_pool"][cache["layer"], cache["table"]]
+    rows = rows.reshape(b, maxp * ps, rows.shape[-1])
+    lens = cache["len"]
+    pos = jnp.arange(maxp * ps, dtype=jnp.int32)[None, :]
+    return rows, jnp.where(pos < lens[:, None], pos, -1), lens - 1
+
+
+def self_attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
+                    ctx: BlockCtx):
+    return (mla_apply if cfg.mla else attn_apply)(cfg, seg, p, x, ctx)
 
 
 # ----------------------------------------------------------------------- MLP
@@ -321,18 +494,22 @@ def mlp_apply(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
 
 def block_param_specs(cfg: ModelConfig, seg: Segment) -> dict:
     norm = lambda: ParamSpec((cfg.d_model,), ("embed",), ones_init)
+    attn = mla_param_specs if cfg.mla else attn_param_specs
     if seg.kind in ("dense", "encoder"):
-        return {"ln1": norm(), "attn": attn_param_specs(cfg),
+        return {"ln1": norm(), "attn": attn(cfg),
                 "ln2": norm(), "mlp": mlp_param_specs(cfg)}
     if seg.kind == "xdecoder":
         return {"ln1": norm(), "attn": attn_param_specs(cfg),
                 "lnx": norm(), "xattn": attn_param_specs(cfg),
                 "ln2": norm(), "mlp": mlp_param_specs(cfg)}
     if seg.kind == "moe":
-        return {"ln1": norm(), "attn": attn_param_specs(cfg),
-                "ln2": norm(),
-                "moe": moe_param_specs(cfg.d_model, cfg.d_ff, cfg.num_experts,
-                                       cfg.moe_sharding)}
+        out = {"ln1": norm(), "attn": attn(cfg), "ln2": norm(),
+               "moe": moe_param_specs(cfg.d_model, cfg.expert_d_ff,
+                                      cfg.num_experts, cfg.moe_sharding)}
+        if cfg.num_shared_experts:
+            out["shared"] = shared_expert_specs(
+                cfg.d_model, cfg.num_shared_experts * cfg.expert_d_ff)
+        return out
     if seg.kind == "hymba":
         return {
             "ln1": norm(), "attn": attn_param_specs(cfg),
@@ -357,7 +534,8 @@ def block_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
     """Returns (x_out, new_cache_layer, aux_losses)."""
     aux: Dict[str, jax.Array] = {}
     if seg.kind in ("dense", "encoder"):
-        h, new_cache = attn_apply(cfg, seg, p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), ctx)
+        h, new_cache = self_attn_apply(cfg, seg, p["attn"],
+                                       rms_norm(x, p["ln1"], cfg.norm_eps), ctx)
         x = x + h
         x = x + mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
         return x, new_cache, aux
@@ -372,12 +550,25 @@ def block_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
         return x, new_cache, aux
 
     if seg.kind == "moe":
-        h, new_cache = attn_apply(cfg, seg, p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), ctx)
+        h, new_cache = self_attn_apply(cfg, seg, p["attn"],
+                                       rms_norm(x, p["ln1"], cfg.norm_eps), ctx)
         x = x + h
-        y, aux = moe_forward(p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps),
-                             cfg.top_k, cfg.capacity_factor,
-                             shard_local=cfg.moe_shard_local,
-                             moe_sharding=cfg.moe_sharding)
+        hn = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if ctx.mode == "train" or (cfg.moe_shard_local and active_mesh()):
+            # capacity dispatch: training, and the sharded dry-run path
+            y, aux = moe_forward(p["moe"], hn, cfg.top_k, cfg.capacity_factor,
+                                 shard_local=cfg.moe_shard_local,
+                                 moe_sharding=cfg.moe_sharding,
+                                 norm_topk_prob=cfg.norm_topk_prob,
+                                 scaling=cfg.routed_scaling_factor)
+        else:
+            y, stats = moe_dropless(p["moe"], hn, cfg.top_k,
+                                    cfg.norm_topk_prob,
+                                    cfg.routed_scaling_factor)
+            if new_cache is not None and "moe_stats" in ctx.cache:
+                new_cache["moe_stats"] = ctx.cache["moe_stats"] + stats
+        if cfg.num_shared_experts:
+            y = y + mlp_apply(cfg, p["shared"], hn)
         return x + y, new_cache, aux
 
     if seg.kind == "hymba":
@@ -422,12 +613,16 @@ def block_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
 
 
 def block_cache_init(cfg: ModelConfig, seg: Segment, batch: int, seq_len: int,
-                     dtype) -> Optional[Dict[str, jax.Array]]:
-    """One layer's cache slice for this segment kind."""
+                     dtype, ring: bool = True) -> Optional[Dict[str, jax.Array]]:
+    """One layer's cache slice for this segment kind (``ring``: see
+    ``attn_cache_init``)."""
     if seg.kind in ("dense", "moe", "encoder", "xdecoder"):
-        return attn_cache_init(cfg, seg, batch, seq_len, dtype)
+        c = attn_cache_init(cfg, seg, batch, seq_len, dtype, ring)
+        if seg.kind == "moe":     # on-device counters (moe.MOE_STATS)
+            c["moe_stats"] = jnp.zeros((len(MOE_STATS),), jnp.int32)
+        return c
     if seg.kind == "hymba":
-        c = attn_cache_init(cfg, seg, batch, seq_len, dtype)
+        c = attn_cache_init(cfg, seg, batch, seq_len, dtype, ring)
         ssm, conv = mamba_state_init(batch, cfg.d_inner, cfg.ssm_state,
                                      cfg.d_conv, dtype)
         c["ssm"], c["conv"] = ssm, conv

@@ -59,8 +59,36 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """DeepSeek yarn's attention magnitude: 0.1 mscale ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, yarn) -> jax.Array:
+    """DeepSeek yarn inverse frequencies [dim // 2] (modeling_deepseek
+    ``DeepseekV2YarnRotaryEmbedding``): the original frequencies below
+    rotary dim ``low``, those divided by ``factor`` above ``high``, a linear
+    ramp between; ``low``/``high`` are where a frequency turns ``beta_fast``
+    and ``beta_slow`` times over the original context."""
+    factor, orig, beta_fast, beta_slow, mscale, mscale_all_dim = yarn
+    if mscale != mscale_all_dim:
+        raise ValueError("yarn with mscale != mscale_all_dim scales cos and "
+                         "sin, which is not implemented")
+
+    def corr_dim(rot):
+        return dim * math.log(orig / (rot * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    extra = rope_freqs(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               mrope_sections: Optional[Tuple[int, int, int]] = None) -> jax.Array:
+               mrope_sections: Optional[Tuple[int, int, int]] = None,
+               yarn=None) -> jax.Array:
     """Rotary embedding (LLaMA half-rotation layout).
 
     x:         [..., S, H, D]
@@ -68,9 +96,13 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
                position triplets; Qwen2-VL §2).  ``mrope_sections`` gives the
                number of *frequency pairs* driven by each component; they must
                sum to D // 2.
+    yarn:      DeepSeek yarn parameters (``ModelConfig.rope_yarn``) with
+               mscale == mscale_all_dim, as published, so that cos and sin
+               keep their scale of 1.
     """
     d2 = x.shape[-1] // 2
-    inv = rope_freqs(x.shape[-1], theta)  # [d2]
+    inv = (rope_freqs(x.shape[-1], theta) if yarn is None
+           else yarn_freqs(x.shape[-1], theta, yarn))  # [d2]
     if mrope_sections is None:
         pos = positions.astype(jnp.float32)  # [B, S]
         angles = pos[..., None] * inv  # [B, S, d2]
@@ -104,6 +136,10 @@ class AttnSpec:
     impl: str = "auto"                 # "dense" | "chunked" | "auto"
     chunk_size: int = 512
     causal_skip: bool = False          # triangular chunk schedule (perf lever)
+    scale: Optional[float] = None      # softmax scale; None = 1/sqrt(D)
+
+    def scale_for(self, d: int) -> float:
+        return self.scale if self.scale is not None else 1.0 / math.sqrt(d)
 
 
 def _group(q: jax.Array, n_kv: int) -> jax.Array:
@@ -149,13 +185,13 @@ def _dense_attention(q, k, v, spec, q_pos, k_pos, k_valid):
     b, sq, h, d = q.shape
     kvh = k.shape[2]
     qg = _group(q, kvh)                                   # [B,Sq,KVH,rep,D]
-    scale = 1.0 / math.sqrt(d)
+    scale = spec.scale_for(d)
     s = jnp.einsum("bqgrd,bkgd->bgrqk", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale         # [B,KVH,rep,Sq,Sk]
     s = s + _mask_bias(q_pos, k_pos, spec, k_valid)[:, None, None]
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bgrqk,bkgd->bqgrd", p, v.astype(jnp.float32))
-    return o.reshape(b, sq, h, d).astype(q.dtype)
+    return o.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def _flash_vjp_attention(q, k, v, spec, q_pos, k_pos, k_valid):
@@ -214,12 +250,12 @@ def _pad_chunks(q, k, v, spec, q_pos, k_pos, k_valid):
 def _chunked_forward(q, k, v, spec, q_pos, k_pos, k_valid):
     """Shared scan: returns (o [B,Sq,H,D], m, l [B,g,r,Sq] fp32)."""
     b, sq, h, d = q.shape
-    kvh = k.shape[2]
+    kvh, dv = k.shape[2], v.shape[-1]
     k, v, k_pos, k_valid, ck = _pad_chunks(q, k, v, spec, q_pos, k_pos, k_valid)
     sk = k.shape[1]
     n_chunks = sk // ck
     qg = _group(q, kvh).astype(jnp.float32)
-    scale = 1.0 / math.sqrt(d)
+    scale = spec.scale_for(d)
 
     def step(carry, xs):
         m, l, acc = carry
@@ -238,26 +274,26 @@ def _chunked_forward(q, k, v, spec, q_pos, k_pos, k_valid):
     rep = h // kvh
     m0 = jnp.full((b, kvh, rep, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, kvh, rep, sq), jnp.float32)
-    a0 = jnp.zeros((b, kvh, rep, sq, d), jnp.float32)
+    a0 = jnp.zeros((b, kvh, rep, sq, dv), jnp.float32)
     xs = (k.reshape(b, n_chunks, ck, kvh, d).swapaxes(0, 1),
-          v.reshape(b, n_chunks, ck, kvh, d).swapaxes(0, 1),
+          v.reshape(b, n_chunks, ck, kvh, dv).swapaxes(0, 1),
           k_pos.reshape(b, n_chunks, ck).swapaxes(0, 1),
           k_valid.reshape(b, n_chunks, ck).swapaxes(0, 1))
     (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), xs)
     o = acc / jnp.maximum(l[..., None], 1e-37)
-    o = o.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d).astype(q.dtype)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, dv).astype(q.dtype)
     return o, m, l
 
 
 def _chunked_backward(q, k, v, spec, q_pos, k_pos, k_valid, o, m, l, do):
     b, sq, h, d = q.shape
-    kvh = k.shape[2]
+    kvh, vd = k.shape[2], v.shape[-1]
     sk_orig = k.shape[1]
     k, v, k_pos, k_valid, ck = _pad_chunks(q, k, v, spec, q_pos, k_pos, k_valid)
     sk = k.shape[1]
     n_chunks = sk // ck
     rep = h // kvh
-    scale = 1.0 / math.sqrt(d)
+    scale = spec.scale_for(d)
 
     qg = _group(q, kvh).astype(jnp.float32)                  # [B,Sq,g,r,D]
     og = _group(o, kvh).astype(jnp.float32)
@@ -279,14 +315,14 @@ def _chunked_backward(q, k, v, spec, q_pos, k_pos, k_valid, o, m, l, do):
         return dq_acc, (dk_c, dv_c)
 
     xs = (k.reshape(b, n_chunks, ck, kvh, d).swapaxes(0, 1),
-          v.reshape(b, n_chunks, ck, kvh, d).swapaxes(0, 1),
+          v.reshape(b, n_chunks, ck, kvh, vd).swapaxes(0, 1),
           k_pos.reshape(b, n_chunks, ck).swapaxes(0, 1),
           k_valid.reshape(b, n_chunks, ck).swapaxes(0, 1))
     dq0 = jnp.zeros((b, sq, kvh, rep, d), jnp.float32)
     dq, (dk_chunks, dv_chunks) = jax.lax.scan(step, dq0, xs)
     dq = dq.reshape(b, sq, h, d).astype(q.dtype)
     dk = dk_chunks.swapaxes(0, 1).reshape(b, sk, kvh, d)[:, :sk_orig].astype(k.dtype)
-    dv = dv_chunks.swapaxes(0, 1).reshape(b, sk, kvh, d)[:, :sk_orig].astype(v.dtype)
+    dv = dv_chunks.swapaxes(0, 1).reshape(b, sk, kvh, vd)[:, :sk_orig].astype(v.dtype)
     return dq, dk, dv
 
 
@@ -308,7 +344,7 @@ def _chunked_attention(q, k, v, spec, q_pos, k_pos, k_valid):
     n_chunks = sk // ck
 
     qg = _group(q, kvh).astype(jnp.float32)               # [B,Sq,KVH,rep,D]
-    scale = 1.0 / math.sqrt(d)
+    scale = spec.scale_for(d)
 
     def attend_chunk(carry, kc, vc, kpos_c, kval_c):
         m, l, acc = carry

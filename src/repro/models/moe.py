@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN: top-k router + sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: top-k router + sort-based capacity dispatch for
+training, dropless grouped-matmul dispatch for serving (``moe_dropless``).
 
 Dispatch is the sort/gather formulation (MegaBlocks/MaxText lineage) rather
 than the dense [T, E, C] one-hot of GShard: assignments are sorted by expert,
@@ -50,15 +51,78 @@ def moe_param_specs(d_model: int, d_ff: int, num_experts: int,
     }
 
 
+def shared_expert_specs(d_model: int, d_ff: int) -> dict:
+    """Always-on experts (DeepSeekMoE), merged into one SwiGLU of width
+    ``n_shared x expert width``."""
+    return {
+        "w_gate": ParamSpec((d_model, d_ff), ("embed", "ffn"), fan_in_init),
+        "w_up": ParamSpec((d_model, d_ff), ("embed", "ffn"), fan_in_init),
+        "w_down": ParamSpec((d_ff, d_model), ("ffn", "embed"), fan_in_init),
+    }
+
+
+#: the dropless dispatch's counters, in the order ``moe_dropless`` returns
+#: them: routed assignments, assignments not computed, experts hit
+MOE_STATS = ("assignments", "not_computed", "experts_hit")
+
+
+def moe_dropless(p: dict, x: jax.Array, top_k: int,
+                 norm_topk_prob: bool = True,
+                 scaling: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """Dropless dispatch for prefill and decode: x [b, s, d] -> (y, stats).
+
+    The router runs in float32 (softmax, greedy top-k; the weights are
+    renormalised only with ``norm_topk_prob``, then scaled by
+    ``scaling``).  Assignments are sorted by expert and each expert's rows
+    go through ``jax.lax.ragged_dot``, a grouped matmul that reads every
+    expert's weights once and computes only the routed rows (on the TPU a
+    compiler kernel that pads each group to its tile; elsewhere a masked
+    reference).  Nothing is dropped, whatever the routing's skew.
+
+    ``stats`` is int32 [3] in ``MOE_STATS`` order: assignments (T x k),
+    assignments not computed (0: the groups cover every sorted row), and
+    experts that received at least one row.
+    """
+    b, s, d = x.shape
+    E = p["router"].shape[1]
+    T, K = b * s, top_k
+    x2 = x.reshape(T, d)
+    logits = jnp.dot(x2.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_ids = jax.lax.top_k(probs, K)                # [T, K]
+    if norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    top_w = top_w * scaling
+
+    flat_e = top_ids.reshape(-1)                            # [T*K]
+    order = jnp.argsort(flat_e, stable=True)
+    tok = (order // K).astype(jnp.int32)
+    groups = jnp.zeros(E, jnp.int32).at[flat_e].add(1)
+    xs = x2[tok]                                            # [T*K, d]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], groups)) * \
+        jax.lax.ragged_dot(xs, p["w_up"], groups)
+    eo = jax.lax.ragged_dot(h, p["w_down"], groups)        # [T*K, d]
+    w_sorted = top_w.reshape(-1)[order]
+    y = jnp.zeros((T, d), jnp.float32).at[tok].add(
+        eo.astype(jnp.float32) * w_sorted[:, None])
+    stats = jnp.stack([jnp.int32(T * K), T * K - groups.sum(),
+                       (groups > 0).sum().astype(jnp.int32)])
+    return y.reshape(b, s, d).astype(x.dtype), stats
+
+
 def moe_forward(p: dict, x: jax.Array, top_k: int,
                 capacity_factor: float = 1.25,
                 shard_local: bool = False,
                 moe_sharding: str = "tp",
+                norm_topk_prob: bool = True,
+                scaling: float = 1.0,
                 ) -> Tuple[jax.Array, dict]:
     """x: [b, s, d] -> (y [b, s, d], aux {lb_loss, z_loss, ...}).
 
-    Tokens over capacity are dropped (contribute zero) — standard
-    capacity-based MoE semantics; capacity_factor sizes the slack.
+    The training dispatch: tokens over capacity are dropped (contribute
+    zero) — standard capacity-based MoE semantics; capacity_factor sizes
+    the slack.  Serving uses ``moe_dropless``.
 
     ``shard_local=True`` (§Perf H1) wraps dispatch in shard_map so the
     sort/gather/scatter run on *local* token shards: GSPMD's auto-lowering
@@ -71,14 +135,19 @@ def moe_forward(p: dict, x: jax.Array, top_k: int,
         from ..distributed.sharding import active_mesh
         mesh = active_mesh()
         if mesh is not None:
+            if not norm_topk_prob or scaling != 1.0:
+                raise NotImplementedError(
+                    "the shard-local dispatch renormalises the top-k weights")
             return _moe_forward_shard_local(p, x, top_k, capacity_factor,
                                             moe_sharding, mesh)
-    return _moe_forward_dense(p, x, top_k, capacity_factor)
+    return _moe_forward_dense(p, x, top_k, capacity_factor,
+                              norm_topk_prob=norm_topk_prob, scaling=scaling)
 
 
 def _moe_forward_dense(p: dict, x: jax.Array, top_k: int,
                        capacity_factor: float = 1.25,
-                       annotate: bool = True) -> Tuple[jax.Array, dict]:
+                       annotate: bool = True, norm_topk_prob: bool = True,
+                       scaling: float = 1.0) -> Tuple[jax.Array, dict]:
     b, s, d = x.shape
     E = p["router"].shape[1]
     T = b * s
@@ -87,7 +156,10 @@ def _moe_forward_dense(p: dict, x: jax.Array, top_k: int,
     logits = (x2 @ p["router"]).astype(jnp.float32)        # [T, E]
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_ids = jax.lax.top_k(probs, top_k)            # [T, K]
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)  # renorm (Mixtral)
+    if norm_topk_prob:                                      # renorm (Mixtral)
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    if scaling != 1.0:
+        top_w = top_w * scaling
 
     K = top_k
     cap = int(max(1, round(T * K / E * capacity_factor)))

@@ -255,12 +255,14 @@ def forward_train(cfg: ModelConfig, params: dict, batch: dict
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=None,
-               memory_len: Optional[int] = None) -> list:
-    """Stacked per-segment caches sized for ``seq_len`` total positions."""
+               memory_len: Optional[int] = None, ring: bool = True) -> list:
+    """Stacked per-segment caches sized for ``seq_len`` total positions;
+    ``ring=False`` gives windowed segments every position too (a prefill
+    whose rows go to pool pages)."""
     dtype = dtype or jnp.dtype(cfg.compute_dtype)
     caches = []
     for seg in cfg.decoder_plan():
-        layer = block_cache_init(cfg, seg, batch, seq_len, dtype)
+        layer = block_cache_init(cfg, seg, batch, seq_len, dtype, ring)
         stacked = jax.tree.map(
             lambda a: jnp.broadcast_to(a, (seg.count,) + a.shape).copy(), layer)
         if cfg.is_encdec and seg.kind == "xdecoder":
@@ -348,26 +350,25 @@ def decode_step(cfg: ModelConfig, params: dict, cache: list,
     return lm_logits(cfg, params, x[:, 0]), new_caches
 
 
-_POOL_KEYS = ("k_pool", "v_pool")
-
-
 def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
                          ctx: BlockCtx, cache_seg):
     """Decode-mode scan; handles cached cross-attention KV for enc-dec.
 
-    A paged cache's stacked ``k_pool``/``v_pool`` [count, P, ps, KVH, D]
+    A paged cache's stacked pools (``<name>_pool`` [count, P, ps, *row]:
+    ``k_pool``/``v_pool`` for GQA, ``ckv_pool`` for MLA; blocks.pool_rows)
     ride in the scan carry with the layer index: each layer writes its
     token into them in place and gathers its pages from them directly
-    (blocks._write_paged_cache / _gather_paged), so no per-layer pool is
-    sliced out and no fresh stacked pool is written back, and a caller
-    that donates the caches gets the pools updated where they lie.
-    Everything else (dense and ring caches, page tables, lengths,
-    recurrent state) scans as ``xs``/``ys``.
+    (blocks._write_paged_cache / _gather_paged and their MLA twins), so no
+    per-layer pool is sliced out and no fresh stacked pool is written
+    back, and a caller that donates the caches gets the pools updated
+    where they lie.  Everything else (dense and ring caches, page tables,
+    lengths, recurrent state, MoE counters) scans as ``xs``/``ys``.
     """
     pools = {}
-    if isinstance(cache_seg, dict) and "k_pool" in cache_seg:
-        pools = {k: cache_seg[k] for k in _POOL_KEYS}
-        cache_seg = {k: v for k, v in cache_seg.items() if k not in _POOL_KEYS}
+    if isinstance(cache_seg, dict):
+        pools = {k: v for k, v in cache_seg.items() if k.endswith("_pool")}
+        cache_seg = {k: v for k, v in cache_seg.items() if k not in pools}
+    pool_keys = tuple(pools)
 
     def body(carry, xs):
         x, layer, pools = carry
@@ -383,9 +384,9 @@ def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
             if isinstance(cache_l, dict) and "xk" in cache_l:
                 new_cache["xk"], new_cache["xv"] = cache_l["xk"], cache_l["xv"]
         if pools:
-            pools = {k: new_cache[k] for k in _POOL_KEYS}
+            pools = {k: new_cache[k] for k in pool_keys}
             new_cache = {k: v for k, v in new_cache.items()
-                         if k not in _POOL_KEYS + ("layer",)}
+                         if k not in pool_keys + ("layer",)}
         return (x, layer + 1, pools), new_cache
 
     (x, _, pools), new_caches = jax.lax.scan(

@@ -12,8 +12,9 @@ paper's application-hints thesis applied to serving (DESIGN.md §16):
                   with bounded restarts;
   * device side — one jitted ``decode_step`` (``decode_program``) that
                   takes the caches ({k_pool, v_pool, table, len} per
-                  attention segment) donated and writes each new token into
-                  the pools in place, and a jitted bucketed ``prefill``.
+                  attention segment, or one ``ckv_pool`` of latent rows for
+                  MLA) donated and writes each new token into the pools in
+                  place, and a jitted bucketed ``prefill``.
 
 Decode batches are fixed-width (max_batch) with empty lanes masked, so one
 compiled executable serves any active-set composition — the continuous
@@ -44,6 +45,8 @@ from ..configs.base import ModelConfig
 from ..core.hints import deadline_headroom_s, fair_shares
 from ..kvcache.allocator import OutOfPages, PageAllocator
 from ..models import transformer as T
+from ..models.blocks import pool_rows
+from ..models.moe import MOE_STATS
 from ..telemetry import spans
 
 
@@ -171,18 +174,23 @@ class ServeEngine:
         self._region_cursor = 0
         self._est_step_s = ecfg.est_step_s
         self._est_prefill_s = ecfg.est_prefill_s
-        self.stats = {"steps": 0, "prefills": 0, "evictions": 0,
-                      "requeues": 0, "admission_pauses": 0,
-                      "slo_deferrals": 0, "slo_misses": 0, "expired": 0,
-                      "shed_requests": 0,
-                      "victim_evictions": 0, "cow_copies": 0,
-                      "shared_pages_mapped": 0, "prefix_hits": 0,
-                      "prefix_drops": 0, "peak_pages_used": 0,
-                      # KV positions the prefill program computed, and how
-                      # many of them a shared prefix's pages already held
-                      "prefill_tokens": 0, "prefill_tokens_held": 0,
-                      "per_tenant": {}}
+        self._stats = {"steps": 0, "prefills": 0, "evictions": 0,
+                       "requeues": 0, "admission_pauses": 0,
+                       "slo_deferrals": 0, "slo_misses": 0, "expired": 0,
+                       "shed_requests": 0,
+                       "victim_evictions": 0, "cow_copies": 0,
+                       "shared_pages_mapped": 0, "prefix_hits": 0,
+                       "prefix_drops": 0, "peak_pages_used": 0,
+                       # KV positions the prefill program computed, and how
+                       # many of them a shared prefix's pages already held
+                       "prefill_tokens": 0, "prefill_tokens_held": 0,
+                       "per_tenant": {}}
         self._caches = self._init_caches()
+        # MoE counters of the prefills (the decode steps' ride in the caches)
+        self._moe_segs = [i for i, seg in enumerate(self.plan)
+                          if seg.kind == "moe"]
+        self._moe_prefill = (jnp.zeros((len(MOE_STATS),), jnp.int32)
+                             if self._moe_segs else None)
         self._decode = decode_program(cfg)
 
         # a named function, so that the trace names the program
@@ -190,6 +198,23 @@ class ServeEngine:
             return T.prefill(cfg, params, batch, cache)
 
         self._prefill = jax.jit(prefill)   # one per bucket
+
+    @property
+    def stats(self) -> dict:
+        """The engine's counters.  For a model with MoE layers, reading them
+        reads the on-device MoE counters once (``moe_assignments``,
+        ``moe_not_computed``: routed assignments of every prefill and
+        decode step, and those not computed; ``moe_decode_experts_hit``:
+        experts that received a row, summed over decode steps and MoE
+        layers); the engine itself never reads them."""
+        if self._moe_segs:
+            dec = sum(np.asarray(self._caches[i]["moe_stats"]).sum(0)
+                      for i in self._moe_segs)
+            pre = np.asarray(self._moe_prefill)
+            for j, name in enumerate(MOE_STATS[:2]):
+                self._stats[f"moe_{name}"] = int(dec[j] + pre[j])
+            self._stats["moe_decode_experts_hit"] = int(dec[2])
+        return self._stats
 
     def register_telemetry(self, registry=None, label=None) -> str:
         """Opt this engine into the telemetry registry (DESIGN.md §15).
@@ -217,7 +242,7 @@ class ServeEngine:
         return t
 
     def _tstats(self, name: str) -> dict:
-        per = self.stats["per_tenant"]
+        per = self._stats["per_tenant"]
         if name not in per:
             per[name] = {k: 0 for k in _TENANT_KEYS}
         return per[name]
@@ -244,22 +269,23 @@ class ServeEngine:
     # --------------------------------------------------------------- caches
 
     def _init_caches(self) -> list:
+        """Per segment: the stacked pools of the block's cache geometry
+        (``blocks.pool_rows``: per-head K and V, or MLA's latent rows),
+        page tables and lengths; MoE segments add their counters."""
         e = self.ecfg
         dt = jnp.dtype(self.cfg.compute_dtype)
         caches = []
         for seg in self.plan:
             if seg.has_attention:
-                c = {
-                    "k_pool": jnp.zeros(
-                        (seg.count, e.num_pages, e.page_size,
-                         self.cfg.num_kv_heads, self.cfg.head_dim), dt),
-                    "v_pool": jnp.zeros(
-                        (seg.count, e.num_pages, e.page_size,
-                         self.cfg.num_kv_heads, self.cfg.head_dim), dt),
-                    "table": jnp.zeros(
-                        (seg.count, e.max_batch, e.max_pages_per_seq), jnp.int32),
-                    "len": jnp.zeros((seg.count, e.max_batch), jnp.int32),
-                }
+                c = {f"{name}_pool": jnp.zeros(
+                        (seg.count, e.num_pages, e.page_size) + row, dt)
+                     for name, row in pool_rows(self.cfg).items()}
+                c["table"] = jnp.zeros(
+                    (seg.count, e.max_batch, e.max_pages_per_seq), jnp.int32)
+                c["len"] = jnp.zeros((seg.count, e.max_batch), jnp.int32)
+                if seg.kind == "moe":
+                    c["moe_stats"] = jnp.zeros(
+                        (seg.count, len(MOE_STATS)), jnp.int32)
                 if seg.has_mamba:
                     from ..models.blocks import block_cache_init
                     mc = block_cache_init(self.cfg, seg, e.max_batch, 8, dt)
@@ -308,10 +334,9 @@ class ServeEngine:
         for i, (seg, c) in enumerate(zip(self.plan, self._caches)):
             if not seg.has_attention:
                 continue
-            k = cache[i]["k"][:, 0, :eff]
-            v = cache[i]["v"][:, 0, :eff]
             self._caches[i] = _install_pages(
-                c, k, v, pages, None, e.page_size, e.max_pages_per_seq)
+                c, _pool_rows_of(self.cfg, cache[i], 0, eff), pages, None,
+                e.page_size, e.max_pages_per_seq)
         entry = PrefixEntry(key=key, seq_id=seq_id, tenant=tenant,
                             n_tokens=eff, pages=pages, pinned=t.pin_fast,
                             last_used=time.time())
@@ -325,7 +350,7 @@ class ServeEngine:
         until those sequences release them (refcounted)."""
         entry = self._prefixes.pop(tuple(key))
         released = self.allocator.free_seq(entry.seq_id)
-        self.stats["prefix_drops"] += 1
+        self._stats["prefix_drops"] += 1
         return released
 
     def _match_prefix(self, prompt: np.ndarray) -> Optional[PrefixEntry]:
@@ -377,7 +402,7 @@ class ServeEngine:
                 self._admission_paused = False
         elif occ >= self.ecfg.admit_high_water:
             self._admission_paused = True
-            self.stats["admission_pauses"] += 1
+            self._stats["admission_pauses"] += 1
         return not self._admission_paused
 
     def _tenant_gate(self, name: str) -> bool:
@@ -396,7 +421,7 @@ class ServeEngine:
         elif occ >= e.admit_high_water:
             self._tenant_paused[name] = True
             paused = True
-            self.stats["admission_pauses"] += 1
+            self._stats["admission_pauses"] += 1
             self._tstats(name)["admission_pauses"] += 1
         return not paused
 
@@ -480,7 +505,7 @@ class ServeEngine:
                     keep.append(req)
                     continue
                 if not relax_slo and self._slo_defer(req, now, degraded):
-                    self.stats["slo_deferrals"] += 1
+                    self._stats["slo_deferrals"] += 1
                     self._tstats(req.tenant)["slo_deferrals"] += 1
                     keep.append(req)
                     continue
@@ -518,9 +543,14 @@ class ServeEngine:
                          -(-S // e.prefill_bucket) * e.prefill_bucket)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :S] = prompt
-        cache = T.init_cache(self.cfg, 1, bucket + 8 + self.cfg.num_meta_tokens)
+        # every position, windowed segments too: the pool keeps them all
+        # and the decode's window mask limits what is read
+        cache = T.init_cache(self.cfg, 1, bucket + 8 + self.cfg.num_meta_tokens,
+                             ring=False)
         _, cache = self._prefill(self.params, {"tokens": jnp.asarray(tokens)},
                                  cache)
+        for i in self._moe_segs:
+            self._moe_prefill = self._moe_prefill + cache[i]["moe_stats"].sum(0)
         return cache
 
     def _prefill_into_pool(self, req: Request) -> None:
@@ -557,13 +587,13 @@ class ServeEngine:
                     self.allocator.share(entry.seq_id, req.rid, n_shared)
                     entry.hits += 1
                     entry.last_used = time.time()
-                    self.stats["prefix_hits"] += 1
-                    self.stats["shared_pages_mapped"] = \
+                    self._stats["prefix_hits"] += 1
+                    self._stats["shared_pages_mapped"] = \
                         self.allocator.shared_mapped
             held = shared_tok if n_shared else 0
             sp.add(tokens=eff, held_tokens=held)
-            self.stats["prefill_tokens"] += eff
-            self.stats["prefill_tokens_held"] += held
+            self._stats["prefill_tokens"] += eff
+            self._stats["prefill_tokens_held"] += held
             tail = need_total - n_shared
             if tail > 0:
                 self.allocator.alloc(req.rid, tail)
@@ -576,7 +606,7 @@ class ServeEngine:
                 # prefill (the shared span re-derives bit-identically;
                 # positions past eff in the page are masked by `len`).
                 self.allocator.make_private(req.rid, n_shared - 1)
-                self.stats["cow_copies"] = self.allocator.cow_copies
+                self._stats["cow_copies"] = self.allocator.cow_copies
                 a0 = (n_shared - 1) * ps
             else:
                 a0 = n_shared * ps
@@ -592,17 +622,15 @@ class ServeEngine:
                         continue
                     # move prefilled contiguous KV into pool pages for this
                     # lane
-                    k = cache[i]["k"][:, 0, a0:eff]
-                    v = cache[i]["v"][:, 0, a0:eff]
                     self._caches[i] = _install_pages(
-                        c, k, v, pages[a0 // ps:], lane, ps,
-                        e.max_pages_per_seq,
+                        c, _pool_rows_of(self.cfg, cache[i], a0, eff), pages[a0 // ps:],
+                        lane, ps, e.max_pages_per_seq,
                         prior_state=cache[i] if seg.has_mamba else None)
         self.active[req.rid] = req
         self.lane_of[req.rid] = lane
         self.seq_len[req.rid] = eff
         req.admitted_at = time.time()
-        self.stats["prefills"] += 1
+        self._stats["prefills"] += 1
         self._tstats(req.tenant)["prefills"] += 1
         self._note_pool()
         dt = time.perf_counter() - t0
@@ -676,7 +704,7 @@ class ServeEngine:
             self.seq_len[rid] += 1
             if req.done:
                 self._retire(rid)
-        self.stats["steps"] += 1
+        self._stats["steps"] += 1
         self._note_pool()
         dt = time.perf_counter() - t0
         self._est_step_s = 0.8 * self._est_step_s + 0.2 * dt
@@ -716,10 +744,10 @@ class ServeEngine:
                 if not seg.has_attention:
                     continue
                 c = dict(c)
-                c["k_pool"] = c["k_pool"].at[:, new].set(c["k_pool"][:, old])
-                c["v_pool"] = c["v_pool"].at[:, new].set(c["v_pool"][:, old])
+                for key in [k for k in c if k.endswith("_pool")]:
+                    c[key] = c[key].at[:, new].set(c[key][:, old])
                 self._caches[i] = c
-        self.stats["cow_copies"] = self.allocator.cow_copies
+        self._stats["cow_copies"] = self.allocator.cow_copies
         return True
 
     def _sync_tables(self, live: List[int]) -> None:
@@ -783,7 +811,7 @@ class ServeEngine:
                     < max_victim_priority)]
             for rid in sorted(victims, key=victim_key):
                 self._evict(rid, requeue=True)
-                self.stats["victim_evictions"] += 1
+                self._stats["victim_evictions"] += 1
                 if alloc.free_pages >= need:
                     return True
         return alloc.free_pages >= need
@@ -797,13 +825,13 @@ class ServeEngine:
         self._free_lanes.append(lane)
         req = self.active.pop(rid)
         self.seq_len.pop(rid, None)
-        self.stats["evictions"] += 1
+        self._stats["evictions"] += 1
         self._tstats(req.tenant)["evictions"] += 1
         if not requeue:
             return
         if req.restarts >= self.ecfg.max_restarts:
             req.expired = True
-            self.stats["expired"] += 1
+            self._stats["expired"] += 1
             self._tstats(req.tenant)["expired"] += 1
             self._finish(req)
             return
@@ -811,7 +839,7 @@ class ServeEngine:
         req.generated = []           # restart decodes from the prompt:
         req.submitted_at = time.time()   # greedy decode re-derives the same
         self.waiting.append(req)         # tokens, so restarts stay byte-safe
-        self.stats["requeues"] += 1
+        self._stats["requeues"] += 1
         self._tstats(req.tenant)["requeues"] += 1
 
     def _retire(self, rid: int) -> None:
@@ -830,8 +858,8 @@ class ServeEngine:
         req.expired = True
         req.slo_miss = True
         req.finished_at = now
-        self.stats["shed_requests"] += 1
-        self.stats["slo_misses"] += 1
+        self._stats["shed_requests"] += 1
+        self._stats["slo_misses"] += 1
         ts = self._tstats(req.tenant)
         ts["shed_requests"] += 1
         ts["slo_misses"] += 1
@@ -842,7 +870,7 @@ class ServeEngine:
         if req.deadline_s is not None and req.first_submitted_at is not None \
                 and req.finished_at - req.first_submitted_at > req.deadline_s:
             req.slo_miss = True
-            self.stats["slo_misses"] += 1
+            self._stats["slo_misses"] += 1
             self._tstats(req.tenant)["slo_misses"] += 1
         ts = self._tstats(req.tenant)
         ts["finished"] += 1
@@ -851,8 +879,8 @@ class ServeEngine:
 
     def _note_pool(self) -> None:
         used = self.allocator.used_pages
-        if used > self.stats["peak_pages_used"]:
-            self.stats["peak_pages_used"] = used
+        if used > self._stats["peak_pages_used"]:
+            self._stats["peak_pages_used"] = used
 
     def run_until_drained(self, max_steps: int = 10_000) -> None:
         for _ in range(max_steps):
@@ -881,29 +909,35 @@ def decode_program(cfg: ModelConfig):
     return jax.jit(decode_step, donate_argnums=(1,))
 
 
-def _install_pages(cache, k, v, pages, lane, page_size, max_pages,
+def _pool_rows_of(cfg: ModelConfig, cache: dict, a0: int, eff: int) -> dict:
+    """Positions [a0, eff) of a one-sequence contiguous cache, per pool:
+    {name: [L, eff - a0, *row]} (``blocks.pool_rows`` names them)."""
+    return {name: cache[name][:, 0, a0:eff] for name in pool_rows(cfg)}
+
+
+def _install_pages(cache, rows, pages, lane, page_size, max_pages,
                    prior_state=None):
-    """Scatter contiguous prefilled KV [L, S, KVH, D] into pool pages.
+    """Scatter contiguous prefilled rows into pool pages: ``rows`` maps a
+    pool's name to [L, S, *row] (K and V [L, S, KVH, D], or MLA's latent
+    rows [L, S, r + dr]), written into ``<name>_pool``.
 
     ``pages`` lists the physical pages receiving the S positions (S == 0
     writes nothing — the whole span was prefix-shared).  ``lane`` is only
     used for recurrent per-lane state (None for prefix pseudo-sequences).
     """
-    L, S = k.shape[0], k.shape[1]
     out = dict(cache)
-    if S:
+    for name, x in rows.items():
+        L, S = x.shape[0], x.shape[1]
+        if not S:
+            continue
         n_pages = -(-S // page_size)
         pad = n_pages * page_size - S
         if pad:
-            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kp = k.reshape(L, n_pages, page_size, *k.shape[2:])
-        vp = v.reshape(L, n_pages, page_size, *v.shape[2:])
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        xp = x.reshape(L, n_pages, page_size, *x.shape[2:])
         idx = jnp.asarray(pages[:n_pages], jnp.int32)
-        out["k_pool"] = cache["k_pool"].at[:, idx].set(
-            kp.astype(cache["k_pool"].dtype))
-        out["v_pool"] = cache["v_pool"].at[:, idx].set(
-            vp.astype(cache["v_pool"].dtype))
+        pool = cache[f"{name}_pool"]
+        out[f"{name}_pool"] = pool.at[:, idx].set(xp.astype(pool.dtype))
     if prior_state is not None and "ssm" in cache and lane is not None:
         out["ssm"] = cache["ssm"].at[:, lane].set(prior_state["ssm"][:, 0])
         out["conv"] = cache["conv"].at[:, lane].set(prior_state["conv"][:, 0])

@@ -5,7 +5,8 @@ All three sources are optional (duck-typed):
 
   engine        a ``ServeEngine`` — its ``stats`` dict plus admission/
                 occupancy gauges read from plain attributes (the engine
-                loop is single-threaded; reads are GIL-atomic)
+                loop is single-threaded; reads are GIL-atomic); for an MoE
+                model reading ``stats`` reads its on-device counters once
   kv            a ``PagedKVCache`` — pool occupancy + host-lock telemetry
   weight_pager  a ``LayerWeightPager`` — layer fill/hit/steal counters
 """
@@ -48,6 +49,16 @@ _ENGINE_COUNTERS = (
      "KV positions computed by admission prefills"),
     ("prefill_tokens_held", "umap_serve_prefill_tokens_held_total",
      "Prefilled positions that a shared prefix's pages already held"),
+)
+
+#: an MoE model's on-device counters, exported when the engine has them
+_MOE_COUNTERS = (
+    ("moe_assignments", "umap_serve_moe_assignments_total",
+     "Routed (token, expert) assignments of prefills and decode steps"),
+    ("moe_not_computed", "umap_serve_moe_not_computed_total",
+     "Routed assignments the dropless dispatch did not compute (always 0)"),
+    ("moe_decode_experts_hit", "umap_serve_moe_decode_experts_hit_total",
+     "Experts hit, summed over decode steps and MoE layers"),
 )
 
 #: per-tenant stats keys exported with a ``tenant`` label (DESIGN.md §16.6);
@@ -113,6 +124,8 @@ class ServeCollector(Collector):
             st = dict(eng.stats)
             fams += [self.c1(m, h, st.get(k, 0))
                      for k, m, h in _ENGINE_COUNTERS]
+            fams += [self.c1(m, h, st[k]) for k, m, h in _MOE_COUNTERS
+                     if k in st]
             fams += [
                 self.g1("umap_serve_active_requests",
                         "Requests currently decoding", len(eng.active)),

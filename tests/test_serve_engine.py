@@ -683,3 +683,62 @@ def test_in_place_decode_matches_xs_ys_path(arch):
         assert eng.stats[key] == oracle_eng.stats[key], key
     assert eng.stats["requeues"] >= 1, "scenario must exhaust the pool"
     assert eng.stats["cow_copies"] >= 2, "both COW paths must run"
+
+
+# ------------------------------------------------ windowed prefill install
+
+
+def serve_capturing_logits(cfg, params, ecfg, prompts, max_new_tokens):
+    """Serve ``prompts`` to the end, keeping each request's decode logits
+    in the order they were served: (engine, requests, {rid: [logits]})."""
+    eng = ServeEngine(cfg, params, ecfg)
+    seen = {}
+    decode = eng._decode
+
+    def capture(params_, caches, tokens, cur):
+        logits, out = decode(params_, caches, tokens, cur)
+        for rid, lane in eng.lane_of.items():
+            seen.setdefault(rid, []).append(np.asarray(logits[lane]))
+        return logits, out
+
+    eng._decode = capture
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=max_new_tokens)
+            for i, p in enumerate(prompts)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_drained(max_steps=100)
+    assert_none_lost(eng, reqs)
+    return eng, reqs, seen
+
+
+def assert_served_logits_match_forward(cfg, params, reqs, seen):
+    """Each request's served logits equal a full ``forward_train`` over its
+    prompt and served tokens within 2e-3 relative / 2e-4 absolute: float32
+    throughout, so only the order of summation differs (the paged gather,
+    chunked or absorbed attention)."""
+    for r in reqs:
+        seq = np.concatenate([r.prompt, r.generated[:-1]])
+        hid, _ = M.forward_train(cfg, params, {"tokens": jnp.asarray([seq])})
+        ref = np.asarray(M.lm_logits(cfg, params, hid)[0, len(r.prompt) - 1:])
+        np.testing.assert_allclose(np.stack(seen[r.rid]), ref, rtol=2e-3,
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("arch", ["mixtral-8x7b", "hymba-1.5b"])
+def test_windowed_prefill_keeps_every_prompt_position(arch):
+    """A windowed segment's prefill for the pool keeps every prompt
+    position (the pool's pages are contiguous positions; the decode's
+    window mask limits what is read).  With prompts longer than the window
+    (W = 8 in both smoke configs), the engine's logits at every served
+    position match a full ``forward_train``."""
+    cfg = get_smoke_config(arch)
+    assert cfg.sliding_window == 8
+    params = M.init_params(cfg, jax.random.key(2))
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(1, cfg.vocab_size, n).astype(np.int32)
+               for n in (20, 13)]
+    _, reqs, seen = serve_capturing_logits(
+        cfg, params, EngineConfig(max_batch=2, page_size=4, num_pages=40,
+                                  max_pages_per_seq=12, prefill_bucket=8),
+        prompts, 6)
+    assert_served_logits_match_forward(cfg, params, reqs, seen)

@@ -148,19 +148,24 @@ def _pool_instructions(hlo: str, pool_shape):
     return found
 
 
-# Qwen2-1.5B's widths are the serve cells'.  smollm-135m's pools (D 64 and
-# 512 pages) take a default device layout with pages minor, which the page
-# gather cannot read in place, so there the program relayouts each pool
-# once into the layer loop and once out of it, and nowhere else.
+# Qwen2-1.5B's and DeepSeek-V2-Lite's widths are the serve cells'.
+# smollm-135m's pools (D 64 and 512 pages) take a default device layout
+# with pages minor, which the page gather cannot read in place, so there
+# the program relayouts each pool once into the layer loop and once out of
+# it, and nowhere else.  DeepSeek's latent pools (one per segment: the
+# dense layer's and the MoE layers') keep rows minor at the padded width.
 @pytest.mark.parametrize("arch,relayouts", [("smollm-135m", 4),
-                                            ("qwen2-1.5b", 0)])
+                                            ("qwen2-1.5b", 0),
+                                            ("deepseek-v2-lite", 0)])
 def test_engine_decode_writes_pools_in_place_on_v5e(one_chip, arch,
                                                     relayouts):
     """The engine's decode program (caches donated) gets the stacked KV
-    pools back in the buffers they came in, and no instruction copies,
-    slices or rebuilds a pool: inside the layer loop a pool-shaped array
-    is only ever the in-place write of the step's tokens."""
+    or latent pools back in the buffers they came in, and no instruction
+    copies, slices or rebuilds a pool: inside the layer loop a pool-shaped
+    array is only ever the in-place write of the step's tokens."""
     cfg = get_config(arch).replace(head_pad_multiple=1)
+    if arch == "deepseek-v2-lite":       # the benchmark's one-chip depth
+        cfg = cfg.replace(num_layers=5)
     ecfg = EngineConfig(max_batch=8, page_size=16, num_pages=512,
                         max_pages_per_seq=32)
     params = jax.eval_shape(partial(T.init_params, cfg), jax.random.key(0))
@@ -171,16 +176,17 @@ def test_engine_decode_writes_pools_in_place_on_v5e(one_chip, arch,
         _on(params, one_chip), _on(caches, one_chip),
         _sds((b,), jnp.int32, one_chip),
         _sds((b,), jnp.int32, one_chip)).compile()
-    pools = [c[k] for c in caches for k in ("k_pool", "v_pool")]
+    pools = [c[k] for c in caches for k in c if k.endswith("_pool")]
     pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
-    found = _pool_instructions(compiled.as_text(), pools[0].shape)
-    writes = [f for f in found if f[1] in ("scatter", "fusion")
-              and f[2].endswith("/scatter")]
-    assert writes, "the tokens' write into the pools was not found"
-    copies = [f for f in found if f[:2] == ("ENTRY", "copy")]
-    assert len(copies) == relayouts, copies
-    assert not [f for f in found if f not in writes + copies]
+    for shape in {p.shape for p in pools}:
+        found = _pool_instructions(compiled.as_text(), shape)
+        writes = [f for f in found if f[1] in ("scatter", "fusion")
+                  and f[2].endswith("/scatter")]
+        assert writes, "the tokens' write into the pools was not found"
+        copies = [f for f in found if f[:2] == ("ENTRY", "copy")]
+        assert len(copies) == relayouts, copies
+        assert not [f for f in found if f not in writes + copies]
 
 
 def test_engine_prefill_compiles_for_v5e(one_chip, smollm):
