@@ -69,6 +69,7 @@ class BlockCtx:
     cur_pos: Optional[jax.Array] = None        # [B] decode position
     memory: Optional[jax.Array] = None         # encoder output [B,Sm,d]
     memory_positions: Optional[jax.Array] = None
+    layer: Optional[jax.Array] = None          # index into a stack the scan passes whole
 
 
 # ----------------------------------------------------------------- attention
@@ -529,6 +530,12 @@ def block_param_specs(cfg: ModelConfig, seg: Segment) -> dict:
     raise ValueError(seg.kind)
 
 
+def moe_is_dropless(cfg: ModelConfig, mode: str) -> bool:
+    """Whether a MoE block dispatches dropless (serving) rather than by
+    capacity (training, and the sharded dry-run path)."""
+    return mode != "train" and not (cfg.moe_shard_local and active_mesh())
+
+
 def block_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
                 ctx: BlockCtx) -> Tuple[jax.Array, Any, Dict[str, jax.Array]]:
     """Returns (x_out, new_cache_layer, aux_losses)."""
@@ -554,8 +561,7 @@ def block_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
                                        rms_norm(x, p["ln1"], cfg.norm_eps), ctx)
         x = x + h
         hn = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if ctx.mode == "train" or (cfg.moe_shard_local and active_mesh()):
-            # capacity dispatch: training, and the sharded dry-run path
+        if not moe_is_dropless(cfg, ctx.mode):
             y, aux = moe_forward(p["moe"], hn, cfg.top_k, cfg.capacity_factor,
                                  shard_local=cfg.moe_shard_local,
                                  moe_sharding=cfg.moe_sharding,
@@ -564,7 +570,7 @@ def block_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
         else:
             y, stats = moe_dropless(p["moe"], hn, cfg.top_k,
                                     cfg.norm_topk_prob,
-                                    cfg.routed_scaling_factor)
+                                    cfg.routed_scaling_factor, ctx.layer)
             if new_cache is not None and "moe_stats" in ctx.cache:
                 new_cache["moe_stats"] = ctx.cache["moe_stats"] + stats
         if cfg.num_shared_experts:

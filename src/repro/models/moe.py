@@ -31,6 +31,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import with_logical_constraint as wlc
+from ..kernels.grouped_matmul import grouped_matmul
 from .common import ParamSpec, fan_in_init, normal_init
 
 
@@ -68,16 +69,24 @@ MOE_STATS = ("assignments", "not_computed", "experts_hit")
 
 def moe_dropless(p: dict, x: jax.Array, top_k: int,
                  norm_topk_prob: bool = True,
-                 scaling: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+                 scaling: float = 1.0, layer=None,
+                 impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
     """Dropless dispatch for prefill and decode: x [b, s, d] -> (y, stats).
 
     The router runs in float32 (softmax, greedy top-k; the weights are
     renormalised only with ``norm_topk_prob``, then scaled by
     ``scaling``).  Assignments are sorted by expert and each expert's rows
-    go through ``jax.lax.ragged_dot``, a grouped matmul that reads every
-    expert's weights once and computes only the routed rows (on the TPU a
-    compiler kernel that pads each group to its tile; elsewhere a masked
-    reference).  Nothing is dropped, whatever the routing's skew.
+    go through a grouped matmul (``kernels.grouped_matmul``, ``impl``) that
+    reads each routed expert's weights once and computes only the routed
+    rows.  Nothing is dropped, whatever the routing's skew.
+
+    Where ``w_gate``/``w_up``/``w_down`` are a segment's whole stack
+    [count, E, k, n], ``layer``'s experts are read where they lie: the
+    grouped matmul takes the stack as [count x E, k, n] (its leading axes
+    merged, no copy) with this layer's groups at ``layer x E + expert``
+    and every other group empty.  A layer scan that sliced the layer's
+    experts out instead would copy them whole on every call, since the
+    grouped matmul cannot read a slice fused.
 
     ``stats`` is int32 [3] in ``MOE_STATS`` order: assignments (T x k),
     assignments not computed (0: the groups cover every sorted row), and
@@ -99,10 +108,20 @@ def moe_dropless(p: dict, x: jax.Array, top_k: int,
     order = jnp.argsort(flat_e, stable=True)
     tok = (order // K).astype(jnp.int32)
     groups = jnp.zeros(E, jnp.int32).at[flat_e].add(1)
+    w = [p["w_gate"], p["w_up"], p["w_down"]]
+    mm_groups = groups
+    if w[0].ndim == 4:
+        count = w[0].shape[0]
+        w = [a.reshape((count * E,) + a.shape[2:]) for a in w]
+        mm_groups = jax.lax.dynamic_update_slice(
+            jnp.zeros(count * E, jnp.int32), groups, (layer * E,))
+
+    def mm(a, b):
+        return grouped_matmul(a, b, mm_groups, impl)
+
     xs = x2[tok]                                            # [T*K, d]
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], groups)) * \
-        jax.lax.ragged_dot(xs, p["w_up"], groups)
-    eo = jax.lax.ragged_dot(h, p["w_down"], groups)        # [T*K, d]
+    h = jax.nn.silu(mm(xs, w[0])) * mm(xs, w[1])
+    eo = mm(h, w[2])                                        # [T*K, d]
     w_sorted = top_w.reshape(-1)[order]
     y = jnp.zeros((T, d), jnp.float32).at[tok].add(
         eo.astype(jnp.float32) * w_sorted[:, None])

@@ -24,7 +24,13 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, Segment
 from ..distributed.sharding import with_logical_constraint as wlc
-from .blocks import BlockCtx, block_apply, block_cache_init, block_param_specs
+from .blocks import (
+    BlockCtx,
+    block_apply,
+    block_cache_init,
+    block_param_specs,
+    moe_is_dropless,
+)
 from .common import (
     ParamSpec,
     init_param_tree,
@@ -120,21 +126,46 @@ def lm_logits(cfg: ModelConfig, params: dict, hidden: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------- scan plumb
 
 
+def _routed_experts(cfg: ModelConfig, seg: Segment, seg_params, mode: str):
+    """(params to scan, stacked routed experts or None).
+
+    A dropless MoE segment's ``w_gate``/``w_up``/``w_down`` [count, E, k, n]
+    stay out of the scanned ``xs``: each layer hands the whole stack to
+    ``moe.moe_dropless`` with its layer index, which reads the layer's
+    experts where they lie.  Scanned, each layer's experts would be a
+    slice, which the grouped matmul cannot take fused, so the compiler
+    would copy them whole on every call."""
+    if seg.kind != "moe" or not moe_is_dropless(cfg, mode):
+        return seg_params, None
+    moe = dict(seg_params["moe"])
+    experts = {k: moe.pop(k) for k in ("w_gate", "w_up", "w_down")}
+    return {**seg_params, "moe": moe}, experts
+
+
+def _with_experts(layer_p, experts):
+    if experts is None:
+        return layer_p
+    return {**layer_p, "moe": {**layer_p["moe"], **experts}}
+
+
 def _scan_segment(cfg: ModelConfig, seg: Segment, seg_params, x, ctx: BlockCtx,
                   cache_seg, collect_aux: bool):
     """Scan one homogeneous segment.  cache_seg: stacked [count, ...] or None."""
     aux0 = {"moe_lb_loss": jnp.zeros((), jnp.float32),
             "moe_z_loss": jnp.zeros((), jnp.float32),
             "moe_drop_frac": jnp.zeros((), jnp.float32)} if collect_aux else None
+    seg_params, experts = _routed_experts(cfg, seg, seg_params, ctx.mode)
+    layers = None if experts is None else jnp.arange(seg.count, dtype=jnp.int32)
 
     def body(carry, xs):
         x, aux_acc = carry
-        layer_p, cache_l = xs
+        layer_p, cache_l, layer = xs
         if _is_dummy(cache_l):
             cache_l = None
         lctx = BlockCtx(mode=ctx.mode, positions=ctx.positions, cache=cache_l,
                         cur_pos=ctx.cur_pos, memory=ctx.memory,
-                        memory_positions=ctx.memory_positions)
+                        memory_positions=ctx.memory_positions, layer=layer)
+        layer_p = _with_experts(layer_p, experts)
         x, new_cache, aux = block_apply(cfg, seg, layer_p, x, lctx)
         if aux_acc is not None and aux:
             aux_acc = {k: aux_acc[k] + aux[k].astype(jnp.float32) for k in aux_acc}
@@ -146,7 +177,7 @@ def _scan_segment(cfg: ModelConfig, seg: Segment, seg_params, x, ctx: BlockCtx,
     (x, aux_acc), new_caches = jax.lax.scan(
         body, (x, aux0),
         (seg_params, cache_seg if cache_seg is not None
-         else _none_like_scan(seg.count)))
+         else _none_like_scan(seg.count), layers))
     return x, aux_acc, new_caches
 
 
@@ -361,9 +392,12 @@ def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
     (blocks._write_paged_cache / _gather_paged and their MLA twins), so no
     per-layer pool is sliced out and no fresh stacked pool is written
     back, and a caller that donates the caches gets the pools updated
-    where they lie.  Everything else (dense and ring caches, page tables,
-    lengths, recurrent state, MoE counters) scans as ``xs``/``ys``.
+    where they lie.  A MoE segment's stacked routed experts are read in
+    place the same way (``_routed_experts``).  Everything else (dense and
+    ring caches, page tables, lengths, recurrent state, MoE counters)
+    scans as ``xs``/``ys``.
     """
+    seg_params, experts = _routed_experts(cfg, seg, seg_params, ctx.mode)
     pools = {}
     if isinstance(cache_seg, dict):
         pools = {k: v for k, v in cache_seg.items() if k.endswith("_pool")}
@@ -376,7 +410,8 @@ def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
         if pools:
             cache_l = {**cache_l, **pools, "layer": layer}
         lctx = BlockCtx(mode="decode", positions=ctx.positions,
-                        cache=cache_l, cur_pos=ctx.cur_pos)
+                        cache=cache_l, cur_pos=ctx.cur_pos, layer=layer)
+        layer_p = _with_experts(layer_p, experts)
         if seg.kind == "xdecoder":
             x, new_cache, _ = _xdecoder_decode(cfg, seg, layer_p, x, lctx)
         else:

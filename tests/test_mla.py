@@ -160,3 +160,75 @@ def test_dropless_moe_computes_every_routed_row(norm):
     assert stats["assignments"] == T * K and stats["not_computed"] == 0
     _, aux = moe_forward(p, x, K, capacity_factor=1.25, norm_topk_prob=norm)
     assert float(aux["moe_drop_frac"]) > 0.2, "the skew must overflow 1.25"
+
+
+def _stacked_moe(count, E, d, f, key):
+    ks = jax.random.split(key, 4)
+    return {"router": jax.random.normal(ks[0], (count, d, E)),
+            "w_gate": jax.random.normal(ks[1], (count, E, d, f)) / 4,
+            "w_up": jax.random.normal(ks[2], (count, E, d, f)) / 4,
+            "w_down": jax.random.normal(ks[3], (count, E, f, d)) / 3}
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("tokens,top_k,routing", [
+    (32, 6, "even"),       # a decode step: 32 lanes x top-6 = 192 rows
+    (512, 6, "even"),      # a prefill bucket: 3,072 rows
+    (192, 1, "one"),       # 192 rows on one expert, the other 63 empty
+    (32, 6, "one"),        # every token on the same six experts
+])
+@pytest.mark.parametrize("layer", [0, 3])
+def test_dropless_moe_reads_the_stacked_experts_in_place(impl, tokens, top_k,
+                                                          routing, layer):
+    """Given a segment's stacked experts [count, E, k, n] and a layer
+    index, the dropless dispatch (the grouped matmul over the stack's
+    [count x E, k, n] view, every other layer's groups empty) equals the
+    dispatch on that layer's experts sliced out, with the same counters."""
+    count, E, d, f = 4, 64, 32, 16
+    p = _stacked_moe(count, E, d, f, jax.random.key(7))
+    if routing == "one":
+        p["router"] = p["router"] * 0.01
+        p["router"] = p["router"].at[:, :, 5:5 + top_k].add(10.0)
+    x = jnp.abs(jax.random.normal(jax.random.key(8), (2, tokens // 2, d)))
+    layer_p = jax.tree.map(lambda a: a[layer], p)
+    want, want_stats = moe_dropless(layer_p, x, top_k, impl="ref")
+    p_in_place = {**p, "router": p["router"][layer]}
+    got, stats = jax.jit(moe_dropless, static_argnums=(2,),
+                         static_argnames="impl")(
+        p_in_place, x, top_k, layer=jnp.int32(layer), impl=impl)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(stats), np.asarray(want_stats))
+    if routing == "one":
+        assert dict(zip(MOE_STATS, np.asarray(stats).tolist()))[
+            "experts_hit"] == top_k
+
+
+def test_engine_decodes_the_same_tokens_as_with_sliced_experts(model,
+                                                               monkeypatch):
+    """The engine serving DeepSeek's smoke model reads each MoE layer's
+    experts from the stack in place; scanning them as sliced per-layer
+    weights instead (the layer scan's earlier form) serves the same
+    tokens, and no routed assignment is left uncomputed either way."""
+    from repro.models import transformer as T
+
+    cfg, params = model
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (10, 6, 13)]
+    ecfg = EngineConfig(max_batch=2, page_size=4, num_pages=24,
+                        max_pages_per_seq=8, prefill_bucket=8)
+    served = {}
+    for how in ("in place", "sliced"):
+        if how == "sliced":
+            monkeypatch.setattr(T, "_routed_experts",
+                                lambda cfg, seg, p, mode: (p, None))
+        eng, reqs, seen = serve_capturing_logits(cfg, params, ecfg,
+                                                 prompts, 5)
+        assert eng.stats["moe_not_computed"] == 0
+        served[how] = ([list(r.generated) for r in reqs], seen)
+    assert served["in place"][0] == served["sliced"][0]
+    for rid, logits in served["in place"][1].items():
+        np.testing.assert_allclose(np.stack(logits),
+                                   np.stack(served["sliced"][1][rid]),
+                                   rtol=1e-5, atol=1e-5)

@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.page_gather import kernel as page_kernels
 from repro.kernels.page_gather import ops as page_ops
 from repro.kernels.paged_attention import kernel as attn_kernel
@@ -99,6 +100,21 @@ def test_paged_attention_compiles_for_v5e(one_chip, b, h, kvh, d, ps):
             _sds((b, per_seq), jnp.int32, one_chip),
             _sds((b,), jnp.int32, one_chip))
     _assert_kernel(attn_kernel.paged_attention.lower(*args).compile())
+
+
+# DeepSeek-V2-Lite's routed experts read in place: four MoE layers' 64
+# experts as one [256, k, n] stack; a decode step's 32 lanes x top-6 rows
+# and a 13,312-token prefill bucket's.
+@pytest.mark.parametrize("m", [192, 13312 * 6])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n):
+    args = (_sds((m, k), jnp.bfloat16, one_chip),
+            _sds((256, k, n), jnp.bfloat16, one_chip),
+            _sds((256,), jnp.int32, one_chip))
+    compiled = jax.jit(partial(grouped_matmul, impl="pallas")).lower(
+        *args).compile()
+    _assert_kernel(compiled)
+    assert compiled.out_info.shape == (m, n)
 
 
 @pytest.fixture(scope="module")
@@ -187,6 +203,39 @@ def test_engine_decode_writes_pools_in_place_on_v5e(one_chip, arch,
         copies = [f for f in found if f[:2] == ("ENTRY", "copy")]
         assert len(copies) == relayouts, copies
         assert not [f for f in found if f not in writes + copies]
+
+
+def test_engine_decode_reads_routed_experts_in_place_on_v5e(one_chip,
+                                                          monkeypatch):
+    """DeepSeek-V2-Lite's decode program, traced as for the chip (Pallas
+    kernels), hands the grouped matmul each MoE segment's stacked experts
+    whole: the stacks enter the layer loop unsliced and are read there
+    through their [count x E, k, n] view, and no instruction has one
+    layer's expert-stack shape, which a copy of a layer's experts out of
+    the stack would have."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("deepseek-v2-lite").replace(head_pad_multiple=1,
+                                                  num_layers=5)
+    ecfg = EngineConfig(max_batch=32, page_size=16, num_pages=512,
+                        max_pages_per_seq=32)
+    params = jax.eval_shape(partial(T.init_params, cfg, dtype=jnp.bfloat16),
+                            jax.random.key(0))
+    engine = SimpleNamespace(cfg=cfg, ecfg=ecfg, plan=cfg.decoder_plan())
+    caches = jax.eval_shape(partial(ServeEngine._init_caches, engine))
+    b = ecfg.max_batch
+    hlo = decode_program(cfg).lower(
+        _on(params, one_chip), _on(caches, one_chip),
+        _sds((b,), jnp.int32, one_chip),
+        _sds((b,), jnp.int32, one_chip)).compile().as_text()
+    moe = params["segments"][1]["moe"]
+    stacks = {moe[k].shape for k in ("w_gate", "w_up", "w_down")}
+    assert stacks == {(4, 64, 2048, 1408), (4, 64, 1408, 2048)}
+    for shape in stacks:
+        assert not _pool_instructions(hlo, shape)
+        view = "[%d,%s]" % (shape[0] * shape[1], ",".join(map(str, shape[2:])))
+        loop_views = re.findall(r"\n\s+%\S+ = bf16" + re.escape(view)
+                                + r"\S* bitcast\(", hlo.split("\nENTRY")[0])
+        assert loop_views, f"no {view} view of the stacked experts in the loop"
 
 
 def test_engine_prefill_compiles_for_v5e(one_chip, smollm):
