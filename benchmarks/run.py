@@ -5,8 +5,6 @@
   lrzip       Fig 4   rolling-hash compression scan
   asteroid    Fig 5/6 image-cube vector tracing, local vs remote store
   nstore      Fig 7/8 YCSB KV transactions + executor scaling
-  paged_kv    (TPU transplant) KV page-size sweep, memory efficiency,
-              weight-pager readahead
   fault_storm §3.3    multi-threaded fault storm: shard-count scaling,
               steal/contention counters (DESIGN.md §12)
   writeback   §3.5    dirty storm: per-page vs coalesced write-back
@@ -81,7 +79,6 @@ SUITES = {
     "lrzip": ("bench_lrzip", "Fig 4"),
     "asteroid": ("bench_asteroid", "Fig 5/6"),
     "nstore": ("bench_nstore", "Fig 7/8"),
-    "paged_kv": ("bench_paged_kv", "TPU transplant"),
     "fault_storm": ("bench_fault_storm", "§3.3 scaling"),
     "writeback": ("bench_writeback", "§3.5 write-back"),
     "tiering": ("bench_tiering", "§3.4 tiered store"),

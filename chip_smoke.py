@@ -13,11 +13,9 @@ Phases (one process, no child processes; any failed check raises):
              a buffer smaller than the parameters (``RegionLayerSource`` ->
              ``page_scatter``/``page_gather``) and 2 steps resident; losses,
              parameters and moments must be bitwise equal.
-  kv_kernel  ``PagedKVCache.attend`` through the Pallas ``paged_attention``
-             kernel against the jnp reference, at smollm decode widths.
 
-The kernel phases also check that the lowered program holds the Pallas
-kernel (``tpu_custom_call``), not the reference.  The script exits non-zero
+The paging phase also checks that the lowered program holds the Pallas
+kernels (``tpu_custom_call``), not the reference.  The script exits non-zero
 when JAX finds no TPU.  Its last line of stdout is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 """
@@ -40,9 +38,6 @@ import numpy as np  # noqa: E402
 from repro.configs.base import ModelConfig  # noqa: E402
 from repro.configs.registry import get_config  # noqa: E402
 from repro.kernels.page_gather.ops import page_gather, page_scatter  # noqa: E402
-from repro.kernels.paged_attention.ops import paged_attention  # noqa: E402
-from repro.kernels.paged_attention.ref import paged_attention_ref  # noqa: E402
-from repro.kvcache.paged_kv import PagedKVCache, PagedKVConfig  # noqa: E402
 from repro.launch.runtime import (  # noqa: E402
     compile_cache_stats,
     device_summary,
@@ -65,13 +60,6 @@ ARCH = "smollm-135m"
 # spread of the reference's largest logit.  A token that is not among the
 # top ones sits about 4 spreads below the largest of 49,152 logits.
 SERVE_GAP_TOL_SPREADS = 0.25
-
-# KV kernel check.  The kernel may run its matmuls as single bf16 MXU passes
-# (8 mantissa bits).  Rounding q and the softmax weights to bf16 moves a
-# score by about 2^-8 of its size and an output, a convex combination of V
-# rows, by about 2^-8 of max|V| per term; 2e-2 covers that for unit-variance
-# data, while a wrong page, head or mask gives errors of order 1.
-KV_ATOL = KV_RTOL = 2e-2
 
 
 def _check(cond: bool, msg: str) -> None:
@@ -255,52 +243,6 @@ def phase_paging(cfg: ModelConfig, *, impl: str, steps: int = 2,
             "resident_s": t1 - t0, "paged_s": t2 - t1}
 
 
-# --------------------------------------------------------------- kv kernel
-
-
-def phase_kv_kernel(cfg: ModelConfig, *, impl: str, batch: int = 8,
-                    page_size: int = 16, seq_lens=(64, 256),
-                    seed: int = 0) -> dict:
-    rng = np.random.default_rng(seed)
-    L, kvh, d, h = (cfg.num_layers, cfg.num_kv_heads, cfg.head_dim,
-                    cfg.num_heads)
-    max_pages = -(-(seq_lens[1] + 1) // page_size) + 1
-    cache = PagedKVCache(PagedKVConfig(
-        num_layers=L, num_kv_heads=kvh, head_dim=d, page_size=page_size,
-        num_pages=batch * max_pages + 1, max_pages_per_seq=max_pages,
-        dtype=cfg.compute_dtype))
-    seq_ids = list(range(batch))
-    for sid in seq_ids:
-        n = int(rng.integers(seq_lens[0], seq_lens[1] + 1))
-        k, v = (jnp.asarray(rng.standard_normal((L, n, kvh, d)), jnp.float32)
-                for _ in range(2))
-        cache.add_sequence(sid, k, v)
-        cache.append_token(sid, k[:, 0], v[:, 0])     # a decode-step write
-    q = jnp.asarray(rng.standard_normal((batch, h, d)), jnp.float32)
-    table, lengths = cache.batch_tables(seq_ids)
-    worst = 0.0
-    for layer in sorted({0, L // 2, L - 1}):
-        out = cache.attend(layer, q, seq_ids, impl=impl)
-        with jax.default_matmul_precision("highest"):
-            ref = paged_attention_ref(q, cache.k_pool[layer],
-                                      cache.v_pool[layer], table, lengths)
-        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
-        _check(out.shape == (batch, h, d) and np.isfinite(out).all(),
-               f"kv_kernel: layer {layer} output {out.shape}")
-        err = np.abs(out - ref)
-        worst = max(worst, float(err.max()))
-        _check(bool((err <= KV_ATOL + KV_RTOL * np.abs(ref)).all()),
-               f"kv_kernel: layer {layer} max error {err.max():.3g} "
-               f"over tolerance {KV_ATOL} + {KV_RTOL}|ref|")
-    if impl == "pallas":
-        _check_kernel_lowered(paged_attention, impl, q, cache.k_pool[0],
-                              cache.v_pool[0], table, lengths)
-    return {"shape": {"B": batch, "H": h, "KVH": kvh, "D": d,
-                      "page_size": page_size},
-            "lengths": [int(x) for x in np.asarray(lengths)],
-            "max_abs_err": worst, "atol": KV_ATOL, "rtol": KV_RTOL}
-
-
 # -------------------------------------------------------------------- main
 
 
@@ -316,8 +258,7 @@ def main(argv=None) -> int:
     cache_dir = enable_compile_cache()
     cfg = get_config(ARCH)
     phases = (("serve", lambda: phase_serve(cfg)),
-              ("paging", lambda: phase_paging(cfg, impl="pallas")),
-              ("kv_kernel", lambda: phase_kv_kernel(cfg, impl="pallas")))
+              ("paging", lambda: phase_paging(cfg, impl="pallas")))
     for name, run in phases:
         t0 = time.perf_counter()
         result = run()

@@ -8,9 +8,10 @@ Layers (bottom-up):
   core/        the paper's contribution: user-space paging (page table, slot
                buffer, fillers/evictors, watermark flushing, backing stores,
                hints) — host-side, real threads + real I/O.
-  kvcache/     on-device analogue: paged KV cache with user page tables.
-  kernels/     Pallas TPU kernels (paged attention, flash attention,
-               page gather/scatter) with jnp oracles.
+  kvcache/     on-device analogue: the serve engine's page allocator (the
+               user page tables of its paged KV pools).
+  kernels/     Pallas TPU kernels (flash attention, page gather/scatter,
+               grouped matmul) with jnp oracles.
   models/      the 10 assigned architectures.
   distributed/ mesh, sharding rules, sequence-sharded decode, compression.
   train/ serve/ data/ ckpt/ launch/   the framework runtime.

@@ -37,9 +37,8 @@ class ModelConfig:
     global_layers: Tuple[int, ...] = ()   # full-attn layers in a SWA model
     attn_chunk: int = 512
     causal_skip: bool = False             # triangular chunk schedule (perf)
-    # §Perf levers (EXPERIMENTS.md): decode-time KV expansion vs grouped GQA,
-    # and shard_map-local MoE dispatch vs GSPMD auto-lowering
-    decode_kv_expand: bool = False        # True = baseline (expand KV to H)
+    # §Perf lever (EXPERIMENTS.md): shard_map-local MoE dispatch vs GSPMD
+    # auto-lowering
     moe_shard_local: bool = True          # False = baseline (GSPMD dispatch)
     parallelism: str = "tp"               # "tp" (model axis on heads/ffn/vocab)
                                           # | "dp" (batch over data AND model —

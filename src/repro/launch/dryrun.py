@@ -165,7 +165,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--variant", default="", help="suffix for A/B artifacts")
     ap.add_argument("--override", action="append", default=[],
-                    help="cfg field override, e.g. decode_kv_expand=true")
+                    help="cfg field override, e.g. moe_shard_local=false")
     args = ap.parse_args(argv)
     overrides = {}
     for ov in args.override:

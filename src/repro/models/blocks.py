@@ -162,9 +162,10 @@ def attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
         # kvcache/allocator) maps logical pages -> pool slots, and the
         # table's pages are gathered for attention.
         assert s == 1
-        new_cache = _write_paged_cache(ctx.cache, k, v)
-        kc, vc, kpos, cur = _gather_paged(new_cache)
-        if cfg.decode_kv_expand or cfg.padded_heads != cfg.num_heads:
+        new_cache = _write_paged_rows(ctx.cache, {"k": k, "v": v})
+        rows, kpos, cur = _gather_paged_rows(new_cache, ("k", "v"))
+        kc, vc = rows["k"], rows["v"]
+        if cfg.padded_heads != cfg.num_heads:
             kce, vce = expand_kv(kc, qh_map), expand_kv(vc, qh_map)
         else:
             kce, vce = kc, vc                  # grouped GQA (§Perf H2)
@@ -184,8 +185,8 @@ def attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
         q = wlc(q, "batch", None, None, None)
         kc = wlc(kc, "batch", "kv_seq", "kv_heads", "head_dim")
         vc = wlc(vc, "batch", "kv_seq", "kv_heads", "head_dim")
-        if cfg.decode_kv_expand or cfg.padded_heads != cfg.num_heads:
-            # baseline / padded-head path: materialize per-Q-head KV
+        if cfg.padded_heads != cfg.num_heads:
+            # padded-head path: materialize per-Q-head KV
             kce, vce = expand_kv(kc, qh_map), expand_kv(vc, qh_map)
         else:
             # §Perf H2: grouped GQA decode — q is replicated over "model" at
@@ -218,10 +219,12 @@ def _write_prefill_cache(cache, rows, positions, window):
     return out
 
 
-def _write_paged_cache(cache, k, v):
-    """Append one token through the page table, in place.
+def _write_paged_rows(cache, rows):
+    """Append one token's rows ({name: [B, 1, *row]} for the names of
+    ``pool_rows``, whose rows share one shape) through the page table, in
+    place.
 
-    cache: {"k_pool"/"v_pool": the segment's stacked [count, P, ps, KVH, D]
+    cache: {"<name>_pool": the segment's stacked [count, P, ps, *row]
     pools, "layer": this layer's index into them, "table": [B, maxp],
     "len": [B]}.  The new token for sequence b goes to physical page
     table[b, len[b] // ps], slot len[b] % ps of pool[layer]; the pools are
@@ -229,50 +232,46 @@ def _write_paged_cache(cache, k, v):
     (transformer._scan_segment_decode).  Page *allocation* happened
     host-side (kvcache/allocator) before this step.
 
-    Each (page, slot, head) row of D is written on its own, which leaves
-    the pool's layout to the gather: for a whole [KVH, D] token the TPU
-    compiler lays the pool out with KVH and D minor, and where the gather
-    wants another (KVH 3, D 64) it then copies the whole stacked pool once
-    a layer.
+    A row of more than one dimension (GQA's [KVH, D]) is written per head:
+    each (page, slot, head) row of D on its own, which leaves the pool's
+    layout to the gather.  For a whole [KVH, D] token the TPU compiler lays
+    the pool out with KVH and D minor, and where the gather wants another
+    (KVH 3, D 64) it then copies the whole stacked pool once a layer.
     """
-    layer = cache["layer"]
-    ps = cache["k_pool"].shape[2]
-    lens = cache["len"]                                   # [B]
+    layer, lens = cache["layer"], cache["len"]            # [B]
+    name, first = next(iter(rows.items()))
+    ps = cache[f"{name}_pool"].shape[2]
+    per_head = first.ndim > 3
+    # page and slot per sequence: [B, 1] beside the heads' [1, KVH], or [B]
+    col = (slice(None), None) if per_head else (slice(None),)
     bi = jnp.arange(lens.shape[0])
-    pages = cache["table"][bi, lens // ps][:, None]       # [B, 1]
-    slots = (lens % ps)[:, None]
-    heads = jnp.arange(k.shape[2])[None, :]               # [1, KVH]
-    return {
-        "k_pool": cache["k_pool"].at[layer, pages, slots, heads].set(
-            k[:, 0].astype(cache["k_pool"].dtype)),
-        "v_pool": cache["v_pool"].at[layer, pages, slots, heads].set(
-            v[:, 0].astype(cache["v_pool"].dtype)),
-        "layer": layer,
-        "table": cache["table"],
-        "len": lens + 1,
-    }
+    at = (layer, cache["table"][bi, lens // ps][col], (lens % ps)[col])
+    if per_head:
+        at += (jnp.arange(first.shape[2])[None, :],)      # [1, KVH]
+    pools = {f"{n}_pool": cache[f"{n}_pool"].at[at].set(
+                 x[:, 0].astype(cache[f"{n}_pool"].dtype))
+             for n, x in rows.items()}
+    return {**pools, "layer": layer, "table": cache["table"],
+            "len": lens + 1}
 
 
-def _gather_paged(cache):
-    """XLA read path: gather table pages -> contiguous [B, S, KVH, D].
+def _gather_paged_rows(cache, names):
+    """XLA read path: this layer's table pages of each named pool ->
+    ({name: [B, maxp * ps, *row]}, kpos [B, maxp * ps], cur_pos [B]).
 
     The layer index is folded into the gather (``pool[layer, table]``), so
     only the table's pages are read and no per-layer pool is materialized.
-    The engine decodes through this gather on every backend; the Pallas
-    kernel in kernels/paged_attention lays its pool out [P, KVH, ps, D]
-    and is not on this path.
+    ``kpos`` is a position's index, or -1 at and past the sequence's
+    post-write length; cur_pos is that length - 1.
     """
     b, maxp = cache["table"].shape
-    ps = cache["k_pool"].shape[2]
-    layer = cache["layer"]
-    kc = cache["k_pool"][layer, cache["table"]]           # [B, maxp, ps, KVH, D]
-    vc = cache["v_pool"][layer, cache["table"]]
-    kc = kc.reshape(b, maxp * ps, *kc.shape[3:])
-    vc = vc.reshape(b, maxp * ps, *vc.shape[3:])
+    ps = cache[f"{names[0]}_pool"].shape[2]
+    pages = {n: cache[f"{n}_pool"][cache["layer"], cache["table"]]
+             for n in names}                              # [B, maxp, ps, *row]
+    rows = {n: x.reshape(b, maxp * ps, *x.shape[3:]) for n, x in pages.items()}
     lens = cache["len"]                                   # post-write lengths
     pos = jnp.arange(maxp * ps, dtype=jnp.int32)[None, :]
-    kpos = jnp.where(pos < lens[:, None], pos, -1)
-    return kc, vc, kpos, lens - 1                          # cur_pos = len-1
+    return rows, jnp.where(pos < lens[:, None], pos, -1), lens - 1
 
 
 def _write_decode_cache(cache, rows, cur_pos, window):
@@ -379,8 +378,9 @@ def mla_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
     if ctx.mode == "decode":
         assert s == 1
         if "ckv_pool" in ctx.cache:
-            new_cache = _write_paged_rows(ctx.cache, row)
-            rows, kpos, cur = _gather_paged_rows(new_cache)
+            new_cache = _write_paged_rows(ctx.cache, {"ckv": row})
+            rows, kpos, cur = _gather_paged_rows(new_cache, ("ckv",))
+            rows = rows["ckv"]
         else:
             new_cache = _write_decode_cache(ctx.cache, {"ckv": row},
                                             ctx.cur_pos, seg.window)
@@ -431,33 +431,6 @@ def _mla_absorbed(cfg, p, q_nope, q_pe, rows, kpos, cur_pos, scale, window):
     o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(w.dtype), w[..., dn:],
                    preferred_element_type=jnp.float32)
     return o.astype(q_nope.dtype)
-
-
-def _write_paged_rows(cache, row):
-    """Append one latent row per sequence through the page table into the
-    segment's stacked ``ckv_pool`` [count, P, ps, width], in place (the
-    layout and carry of ``_write_paged_cache``)."""
-    layer = cache["layer"]
-    ps = cache["ckv_pool"].shape[2]
-    lens = cache["len"]
-    bi = jnp.arange(lens.shape[0])
-    pages = cache["table"][bi, lens // ps]
-    return {
-        "ckv_pool": cache["ckv_pool"].at[layer, pages, lens % ps].set(
-            row[:, 0].astype(cache["ckv_pool"].dtype)),
-        "layer": layer, "table": cache["table"], "len": lens + 1,
-    }
-
-
-def _gather_paged_rows(cache):
-    """The table's latent rows of this layer -> [B, maxp * ps, width]."""
-    b, maxp = cache["table"].shape
-    ps = cache["ckv_pool"].shape[2]
-    rows = cache["ckv_pool"][cache["layer"], cache["table"]]
-    rows = rows.reshape(b, maxp * ps, rows.shape[-1])
-    lens = cache["len"]
-    pos = jnp.arange(maxp * ps, dtype=jnp.int32)[None, :]
-    return rows, jnp.where(pos < lens[:, None], pos, -1), lens - 1
 
 
 def self_attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,

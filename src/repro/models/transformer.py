@@ -389,7 +389,7 @@ def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
     ``k_pool``/``v_pool`` for GQA, ``ckv_pool`` for MLA; blocks.pool_rows)
     ride in the scan carry with the layer index: each layer writes its
     token into them in place and gathers its pages from them directly
-    (blocks._write_paged_cache / _gather_paged and their MLA twins), so no
+    (blocks._write_paged_rows / _gather_paged_rows), so no
     per-layer pool is sliced out and no fresh stacked pool is written
     back, and a caller that donates the caches gets the pools updated
     where they lie.  A MoE segment's stacked routed experts are read in

@@ -103,7 +103,6 @@ class EngineConfig:
     prefill_bucket: int = 64            # prompts padded to this length
     admit_high_water: float = 0.85      # stop admitting (paper §3.5 analogue)
     admit_low_water: float = 0.60       # resume admitting
-    attn_impl: str = "ref"              # paged kernel impl for pool reads
     # --- multi-tenant serving (DESIGN.md §16) ------------------------------
     prefix_sharing: bool = True         # COW prompt-prefix page sharing
     slo_admission: bool = True          # order admission by deadline headroom

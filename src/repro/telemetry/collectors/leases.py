@@ -5,10 +5,6 @@ Sources are all optional — pass whichever exist in the process:
 
   service        a ``PagingService`` (lease grants / blocked evictions,
                  via the lock-free ``stats`` aggregation)
-  kv             a ``PagedKVCache`` (``lease_kv`` grants, pinned
-                 sequences; ``stats()`` takes the KV host-metadata lock,
-                 which is never held across store I/O — documented
-                 exception to the no-locks scrape rule)
   weight_source  a ``RegionLayerSource`` (staging-copy fallbacks — a
                  nonzero rate means the zero-copy path is disabled)
 """
@@ -24,10 +20,9 @@ from .base import Collector
 class LeaseCollector(Collector):
     kind = "leases"
 
-    def __init__(self, service=None, kv=None, weight_source=None, label=None):
+    def __init__(self, service=None, weight_source=None, label=None):
         super().__init__(label)
         self.service = service
-        self.kv = kv
         self.weight_source = weight_source
 
     def collect(self) -> List[MetricFamily]:
@@ -40,19 +35,6 @@ class LeaseCollector(Collector):
                 self.c1("umap_leases_blocked_evictions_total",
                         "Victim/clean skips due to live leases",
                         snap["lease_blocked_evictions"]),
-            ]
-        if self.kv is not None:
-            st = self.kv.stats()
-            fams += [
-                self.c1("umap_kv_leases_granted_total",
-                        "lease_kv() grants on the paged KV cache",
-                        st["leases"]),
-                self.c1("umap_kv_lease_blocked_evictions_total",
-                        "KV window evictions refused by a live lease",
-                        st["lease_blocked_evictions"]),
-                self.g1("umap_kv_leased_sequences",
-                        "Sequences currently pinned by a lease",
-                        st["leased_sequences"]),
             ]
         if self.weight_source is not None:
             fams.append(self.c1(

@@ -1,13 +1,11 @@
-"""Serve collector: serving-engine, paged-KV-cache, and weight-pager
-counters.
+"""Serve collector: serving-engine and weight-pager counters.
 
-All three sources are optional (duck-typed):
+Both sources are optional (duck-typed):
 
   engine        a ``ServeEngine`` — its ``stats`` dict plus admission/
                 occupancy gauges read from plain attributes (the engine
                 loop is single-threaded; reads are GIL-atomic); for an MoE
                 model reading ``stats`` reads its on-device counters once
-  kv            a ``PagedKVCache`` — pool occupancy + host-lock telemetry
   weight_pager  a ``LayerWeightPager`` — layer fill/hit/steal counters
 """
 
@@ -86,14 +84,6 @@ _TENANT_COUNTERS = (
      "Per-tenant decoded tokens"),
 )
 
-_KV_GAUGES = (
-    ("pages_used", "umap_kv_pages_used", "Device pool pages in use"),
-    ("pages_free", "umap_kv_pages_free", "Device pool pages free"),
-    ("occupancy", "umap_kv_occupancy_ratio", "Device pool occupancy [0,1]"),
-    ("sequences", "umap_kv_sequences", "Live sequences in the cache"),
-    ("page_bytes", "umap_kv_page_size_bytes", "Bytes per KV page (K+V)"),
-)
-
 _WEIGHT_COUNTERS = (
     ("fills", "umap_weight_fills_total", "Layers fetched host-to-device"),
     ("hits", "umap_weight_hits_total", "Layer requests served from a slot"),
@@ -111,10 +101,9 @@ _WEIGHT_COUNTERS = (
 class ServeCollector(Collector):
     kind = "serve"
 
-    def __init__(self, engine=None, kv=None, weight_pager=None, label=None):
+    def __init__(self, engine=None, weight_pager=None, label=None):
         super().__init__(label)
         self.engine = engine
-        self.kv = kv
         self.weight_pager = weight_pager
 
     def collect(self) -> List[MetricFamily]:
@@ -152,34 +141,6 @@ class ServeCollector(Collector):
                     for tenant in sorted(per_tenant):
                         fam.add(per_tenant[tenant].get(key, 0), tenant=tenant)
                     fams.append(fam)
-        if self.kv is not None:
-            st = self.kv.stats()
-            fams += [self.g1(m, h, st[k]) for k, m, h in _KV_GAUGES]
-            fams += [
-                self.c1("umap_kv_auto_evicted_pages_total",
-                        "Window-prefix pages auto-evicted", st["auto_evicted_pages"]),
-                self.c1("umap_kv_host_lock_contended_total",
-                        "Contended KV host-metadata lock acquisitions",
-                        st["host_lock_contended"]),
-                self.c1("umap_kv_cow_copies_total",
-                        "Copy-on-write page copies in the KV pool",
-                        st.get("cow_copies", 0)),
-                self.g1("umap_kv_shared_pages",
-                        "Physical pages currently mapped by >1 sequence",
-                        st.get("shared_pages", 0)),
-                self.c1("umap_kv_shared_pages_mapped_total",
-                        "Prefix pages mapped without copying",
-                        st.get("shared_pages_mapped", 0)),
-            ]
-            phases = self.gauge(
-                "umap_kv_sequences_by_phase",
-                "Live sequences per detected access-pattern phase")
-            counts: dict = {}
-            for phase in st["phases"].values():
-                counts[phase] = counts.get(phase, 0) + 1
-            for phase, n in sorted(counts.items()):
-                phases.add(n, phase=phase)
-            fams.append(phases)
         if self.weight_pager is not None:
             wp = self.weight_pager
             st = dict(wp.stats)

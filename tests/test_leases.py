@@ -654,33 +654,3 @@ def test_weight_pager_region_source_zero_staging():
         pager.close()
     finally:
         uunmap(region)
-
-
-def test_paged_kv_lease_gathers_without_staging_and_pins_sequence():
-    pytest.importorskip("jax")
-    import jax.numpy as jnp
-    from repro.kvcache.paged_kv import PagedKVCache, PagedKVConfig
-
-    cfg = PagedKVConfig(num_layers=2, num_kv_heads=2, head_dim=8,
-                        page_size=4, num_pages=32)
-    pc = PagedKVCache(cfg)
-    k = jnp.arange(2 * 10 * 2 * 8, dtype=jnp.float32).reshape(2, 10, 2, 8)
-    pc.add_sequence(0, k, k + 1)
-    with pc.lease_kv(0, layer=1) as ls:
-        pages = pc.allocator.pages_of(0)
-        want = jnp.take(pc.k_pool[1], jnp.asarray(pages), axis=0)
-        assert jnp.allclose(ls.k, want)
-        # pages come back in the pool layout [n, KVH, ps, D]: page 1 slot 2
-        # of KV head 1 is token 6 of layer 1
-        assert ls.k.shape == (3, 2, 4, 8)
-        tok = k[1, 6, 1]
-        assert jnp.array_equal(ls.k[1, 1, 2], tok.astype(ls.k.dtype))
-        assert jnp.array_equal(ls.v[1, 1, 2], (tok + 1).astype(ls.v.dtype))
-        with pytest.raises(RuntimeError, match="lease"):
-            pc.release(0)                          # pinned against free
-        assert pc.evict_window_prefix(0, 4) == []  # and against eviction
-    st = pc.stats()
-    assert st["leases"] == 1
-    assert st["lease_blocked_evictions"] == 1
-    assert st["leased_sequences"] == 0             # released
-    assert pc.release(0) > 0                       # free works after release

@@ -19,7 +19,6 @@ from repro.distributed.collectives import (
     init_error_state,
 )
 from repro.kvcache.allocator import OutOfPages, PageAllocator
-from repro.kvcache.paged_kv import ContiguousKVCache, PagedKVCache, PagedKVConfig
 from repro.serve.engine import EngineConfig, Request, ServeEngine
 from repro.serve.weight_pager import LayerWeightPager
 from repro.train.trainer import Trainer, TrainerConfig
@@ -44,59 +43,6 @@ def test_page_allocator_accounting():
     assert dropped == p2[:2] and a.pages_of(2) == p2[2:]
     row = a.table_for(2, 8)
     assert list(row[:2]) == p2[2:] and (row[2:] == 0).all()
-
-
-def test_paged_kv_cache_roundtrip_and_attend():
-    cfg = PagedKVConfig(num_layers=2, num_kv_heads=2, head_dim=8,
-                        page_size=4, num_pages=16, max_pages_per_seq=4)
-    cache = PagedKVCache(cfg)
-    rng = np.random.default_rng(0)
-    k = jnp.asarray(rng.normal(size=(2, 10, 2, 8)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(2, 10, 2, 8)), jnp.float32)
-    cache.add_sequence(7, k, v)
-    assert cache.seq_len[7] == 10
-    cache.append_token(7, k[:, 0], v[:, 0])
-    assert cache.seq_len[7] == 11
-    # pool layout [L, P, KVH, ps, D]: token t of the sequence sits in page
-    # t // ps at slot t % ps of every KV head
-    assert cache.k_pool.shape == (2, 16, 2, 4, 8)
-    pages = cache.allocator.pages_of(7)
-    k, v = k.astype(cache.k_pool.dtype), v.astype(cache.v_pool.dtype)
-    np.testing.assert_array_equal(cache.k_pool[:, pages[1], :, 1],
-                                  k[:, 5])                 # token 5
-    np.testing.assert_array_equal(cache.v_pool[:, pages[2], :, 2],
-                                  v[:, 0])                 # appended token 10
-    q = jnp.asarray(rng.normal(size=(1, 4, 8)), jnp.float32)
-    out = cache.attend(0, q, [7], impl="ref")
-    assert out.shape == (1, 4, 8) and np.isfinite(np.asarray(out)).all()
-    # the paged read equals dense GQA attention over the 11 tokens
-    ks = jnp.concatenate([k[0], k[0, :1]]).astype(jnp.float32)  # [11, KVH, D]
-    vs = jnp.concatenate([v[0], v[0, :1]]).astype(jnp.float32)
-    qg = q[0].reshape(2, 2, 8)                             # [KVH, rep, D]
-    w = jax.nn.softmax(jnp.einsum("grd,sgd->grs", qg, ks) / np.sqrt(8), -1)
-    want = jnp.einsum("grs,sgd->grd", w, vs).reshape(1, 4, 8)
-    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
-    stats = cache.stats()
-    assert stats["sequences"] == 1 and stats["pages_used"] == 3
-    assert cache.release(7) == 3
-    assert cache.allocator.used_pages == 0
-
-
-def test_paged_vs_contiguous_memory_accounting():
-    """The paged cache reserves ~actual tokens; contiguous reserves max_len."""
-    paged = PagedKVConfig(num_layers=1, num_kv_heads=1, head_dim=4,
-                          page_size=4, num_pages=64)
-    pc = PagedKVCache(paged)
-    cc = ContiguousKVCache(1, 1, 4, max_seqs=8, max_len=64)
-    rng = np.random.default_rng(0)
-    for sid, L in enumerate([5, 9, 17]):
-        k = jnp.asarray(rng.normal(size=(1, L, 1, 4)), jnp.float32)
-        pc.add_sequence(sid, k, k)
-        cc.add_sequence(sid, k, k)
-    paged_tokens = pc.allocator.used_pages * paged.page_size
-    assert paged_tokens == 8 + 12 + 20            # rounded up to pages
-    assert cc.reserved_tokens() == 3 * 64          # mmap-style over-reserve
-    assert cc.used_tokens() == 31
 
 
 # ------------------------------------------------------------- serve engine

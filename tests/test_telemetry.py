@@ -425,17 +425,19 @@ class TestTieringCollector:
 # ----------------------------------------------------------- LeaseCollector
 
 
-class _FakeKV:
-    def __init__(self):
-        self.n = 0
+class _FakeService:
+    """A ``PagingService``'s lock-free ``stats`` aggregation."""
 
-    def stats(self):
-        return {"leases": 3 + self.n, "lease_blocked_evictions": 1,
-                "leased_sequences": 2, "pages_used": 5, "pages_free": 3,
-                "occupancy": 0.625, "page_bytes": 1 << 14, "sequences": 4,
-                "cow_copies": 2, "shared_pages": 1, "shared_pages_mapped": 3,
-                "auto_evicted_pages": 6, "host_lock_contended": 0,
-                "phases": {1: "stream", 2: "stream", 3: "random"}}
+    def __init__(self):
+        self.stats = self
+
+    def snapshot(self):
+        return {"leases": 3, "lease_blocked_evictions": 1}
+
+
+class _FakeWeightSource:
+    def __init__(self):
+        self.staging_copies = 17
 
 
 class TestLeaseCollector:
@@ -453,25 +455,23 @@ class TestLeaseCollector:
             uunmap(r)
 
     def test_kv_and_weight_source_metrics(self):
-        class _FakeWeightSource:
-            staging_copies = 17
-
         fams = families_of(LeaseCollector(
-            kv=_FakeKV(), weight_source=_FakeWeightSource(), label="L"))
-        assert set(fams) == {"umap_kv_leases_granted_total",
-                             "umap_kv_lease_blocked_evictions_total",
-                             "umap_kv_leased_sequences",
+            service=_FakeService(), weight_source=_FakeWeightSource(),
+            label="L"))
+        assert set(fams) == {"umap_leases_granted_total",
+                             "umap_leases_blocked_evictions_total",
                              "umap_weight_staging_copies_total"}
-        assert fams["umap_kv_leased_sequences"].kind == "gauge"
-        assert fams["umap_kv_leases_granted_total"].samples[0][2] == 3
+        assert fams["umap_weight_staging_copies_total"].kind == "counter"
+        assert fams["umap_leases_granted_total"].samples[0][2] == 3
         assert fams["umap_weight_staging_copies_total"].samples[0][2] == 17
 
     def test_kv_counter_monotonic(self):
-        kv = _FakeKV()
-        col = LeaseCollector(kv=kv, label="L")
-        v1 = families_of(col)["umap_kv_leases_granted_total"].samples[0][2]
-        kv.n += 5
-        v2 = families_of(col)["umap_kv_leases_granted_total"].samples[0][2]
+        src = _FakeWeightSource()
+        col = LeaseCollector(weight_source=src, label="L")
+        name = "umap_weight_staging_copies_total"
+        v1 = families_of(col)[name].samples[0][2]
+        src.staging_copies += 5
+        v2 = families_of(col)[name].samples[0][2]
         assert v2 == v1 + 5
 
     def test_empty_collector_yields_nothing(self):
@@ -547,13 +547,6 @@ SERVE_TENANT_FAMILIES = {
     "umap_serve_tenant_finished_total",
     "umap_serve_tenant_tokens_generated_total",
 }
-SERVE_KV_FAMILIES = {
-    "umap_kv_pages_used", "umap_kv_pages_free", "umap_kv_occupancy_ratio",
-    "umap_kv_sequences", "umap_kv_page_size_bytes",
-    "umap_kv_auto_evicted_pages_total", "umap_kv_host_lock_contended_total",
-    "umap_kv_cow_copies_total", "umap_kv_shared_pages",
-    "umap_kv_shared_pages_mapped_total", "umap_kv_sequences_by_phase",
-}
 SERVE_WEIGHT_FAMILIES = {
     "umap_weight_fills_total", "umap_weight_hits_total",
     "umap_weight_waits_total", "umap_weight_evictions_total",
@@ -590,13 +583,6 @@ class TestServeCollector:
             assert got == {t: float(st[key]) for t, st in per.items()}, \
                 fam_name
 
-    def test_kv_families_and_phase_label(self):
-        fams = families_of(ServeCollector(kv=_FakeKV(), label="e"))
-        assert set(fams) == SERVE_KV_FAMILIES
-        phases = {lab["phase"]: v for _, lab, v in
-                  fams["umap_kv_sequences_by_phase"].samples}
-        assert phases == {"stream": 2, "random": 1}
-
     def test_weight_pager_families(self):
         fams = families_of(ServeCollector(weight_pager=_FakeWeightPager(),
                                           label="w"))
@@ -606,10 +592,10 @@ class TestServeCollector:
 
     def test_all_sources_compose(self):
         fams = families_of(ServeCollector(
-            engine=_FakeEngine(), kv=_FakeKV(),
-            weight_pager=_FakeWeightPager(), label="all"))
+            engine=_FakeEngine(), weight_pager=_FakeWeightPager(),
+            label="all"))
         assert set(fams) == (SERVE_ENGINE_FAMILIES | SERVE_TENANT_FAMILIES
-                             | SERVE_KV_FAMILIES | SERVE_WEIGHT_FAMILIES)
+                             | SERVE_WEIGHT_FAMILIES)
 
 
 # --------------------------------------------------------- ProcessCollector

@@ -25,8 +25,6 @@ from repro.configs.registry import get_config
 from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.page_gather import kernel as page_kernels
 from repro.kernels.page_gather import ops as page_ops
-from repro.kernels.paged_attention import kernel as attn_kernel
-from repro.kernels.paged_attention import ops as attn_ops
 from repro.models import transformer as T
 from repro.serve.engine import EngineConfig, ServeEngine, decode_program
 
@@ -86,20 +84,6 @@ def test_page_kernels_compile_for_v5e(one_chip, pool_shape, op):
         pages = _sds((n,) + pool_shape[1:], jnp.float32, one_chip)
         compiled = page_kernels.page_scatter.lower(pool, ids, pages).compile()
     _assert_kernel(compiled)
-
-
-@pytest.mark.parametrize("b,h,kvh,d,ps", [
-    (8, 9, 3, 64, 16),        # smollm-135m decode
-    (32, 32, 8, 128, 16),     # llama3-8b-class decode
-])
-def test_paged_attention_compiles_for_v5e(one_chip, b, h, kvh, d, ps):
-    pages, per_seq = 1024, 32
-    args = (_sds((b, h, d), jnp.bfloat16, one_chip),
-            _sds((pages, kvh, ps, d), jnp.bfloat16, one_chip),
-            _sds((pages, kvh, ps, d), jnp.bfloat16, one_chip),
-            _sds((b, per_seq), jnp.int32, one_chip),
-            _sds((b,), jnp.int32, one_chip))
-    _assert_kernel(attn_kernel.paged_attention.lower(*args).compile())
 
 
 # DeepSeek-V2-Lite's routed experts read in place: four MoE layers' 64
@@ -249,8 +233,7 @@ def test_engine_prefill_compiles_for_v5e(one_chip, smollm):
     assert compiled.out_info[0].shape == (1, cfg.d_model)
 
 
-@pytest.mark.parametrize("op", ["page_gather", "page_scatter",
-                                "paged_attention"])
+@pytest.mark.parametrize("op", ["page_gather", "page_scatter"])
 def test_chip_smoke_kernel_check_sees_the_kernel(one_chip, op):
     """``chip_smoke.py``'s proof that a phase ran the Pallas kernel accepts
     the ``impl="pallas"`` dispatch and refuses the jnp reference."""
@@ -258,19 +241,11 @@ def test_chip_smoke_kernel_check_sees_the_kernel(one_chip, op):
     spec = importlib.util.spec_from_file_location("chip_smoke", path)
     chip_smoke = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(chip_smoke)
-    if op == "paged_attention":
-        fn = attn_ops.paged_attention
-        args = (_sds((8, 9, 64), jnp.bfloat16, one_chip),
-                _sds((64, 3, 16, 64), jnp.bfloat16, one_chip),
-                _sds((64, 3, 16, 64), jnp.bfloat16, one_chip),
-                _sds((8, 8), jnp.int32, one_chip),
-                _sds((8,), jnp.int32, one_chip))
-    else:
-        fn = getattr(page_ops, op)
-        args = (_sds((64, 1024, 128), jnp.float32, one_chip),
-                _sds((16,), jnp.int32, one_chip))
-        if op == "page_scatter":
-            args += (_sds((16, 1024, 128), jnp.float32, one_chip),)
+    fn = getattr(page_ops, op)
+    args = (_sds((64, 1024, 128), jnp.float32, one_chip),
+            _sds((16,), jnp.int32, one_chip))
+    if op == "page_scatter":
+        args += (_sds((16, 1024, 128), jnp.float32, one_chip),)
     chip_smoke._check_kernel_lowered(fn, "pallas", *args)
     with pytest.raises(RuntimeError, match="no tpu_custom_call"):
         chip_smoke._check_kernel_lowered(fn, "ref", *args)
