@@ -26,9 +26,14 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig, Segment
 from ..distributed.sharding import active_mesh
 from ..distributed.sharding import with_logical_constraint as wlc
+from ..kernels.paged_latent_attention import (
+    attend_rows,
+    paged_latent_attention,
+    reads_live_pages,
+    visible,
+)
 from .common import ParamSpec, fan_in_init, normal_init, ones_init, zeros_init
 from .layers import (
-    NEG_INF,
     AttnSpec,
     apply_rope,
     attention,
@@ -299,6 +304,13 @@ def pool_rows(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
     return {"k": row, "v": row}
 
 
+def paged_reads_live_pages(cfg: ModelConfig) -> bool:
+    """Whether paged decode reads only each lane's live pages: MLA's
+    latent reader off the CPU (``kernels.paged_latent_attention``); GQA's
+    ``_gather_paged_rows`` reads every table page."""
+    return cfg.mla and reads_live_pages()
+
+
 #: MLA's cached row is padded with zeros to a multiple of the TPU's lane
 #: width: for a 576-wide row (DeepSeek-V2) the device's default layout of
 #: the stacked pool puts pages minor, and the decode step then relayouts
@@ -356,7 +368,8 @@ def mla_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
     latent (k = [c W_uk, k_pe], v = c W_uv) and attend causally.  Decode is
     absorbed: the query is taken into the latent space (q_nope W_uk^T), so
     scores and the weighted sum run over the cached latent rows directly,
-    and only the head outputs are decompressed (W_uv).  The cache holds,
+    and only the head outputs are decompressed (W_uv); the paged pool is
+    read by ``kernels.paged_latent_attention``.  The cache holds,
     per token, the normalised latent and the roped shared key (one row of
     ``kv_lora_rank + qk_rope_head_dim``, zero-padded to ``mla_row_width``)."""
     b, s, _ = x.shape
@@ -377,17 +390,24 @@ def mla_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
 
     if ctx.mode == "decode":
         assert s == 1
+        w = p["wkv_b"].reshape(r, H, dn + dv)
+        qc = _mla_latent_query(w, q[:, 0, :, :dn], q_pe[:, 0], row.shape[-1])
         if "ckv_pool" in ctx.cache:
+            # the latent pool's one reader: only each lane's live pages on
+            # the TPU, every table page on the CPU (DESIGN.md §19.5)
             new_cache = _write_paged_rows(ctx.cache, {"ckv": row})
-            rows, kpos, cur = _gather_paged_rows(new_cache, ("ckv",))
-            rows = rows["ckv"]
+            o_lat = paged_latent_attention(
+                qc, new_cache["ckv_pool"], new_cache["layer"],
+                new_cache["table"], new_cache["len"], scale, seg.window)
         else:
             new_cache = _write_decode_cache(ctx.cache, {"ckv": row},
                                             ctx.cur_pos, seg.window)
-            rows, kpos, cur = new_cache["ckv"], new_cache["pos"], ctx.cur_pos
-        o = _mla_absorbed(cfg, p, q[:, 0, :, :dn], q_pe[:, 0], rows, kpos,
-                          cur, scale, seg.window)
-        return o.reshape(b, s, H * dv) @ p["wo"], new_cache
+            kpos, cur = new_cache["pos"], ctx.cur_pos[:, None]
+            ok = (kpos >= 0) & visible(kpos, cur + 1, seg.window)
+            o_lat = attend_rows(qc, new_cache["ckv"], ok, scale)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat[..., :r].astype(w.dtype),
+                       w[..., dn:], preferred_element_type=jnp.float32)
+        return o.astype(q.dtype).reshape(b, s, H * dv) @ p["wo"], new_cache
 
     kv = (c @ p["wkv_b"]).reshape(b, s, H, dn + dv)
     k = jnp.concatenate(
@@ -404,33 +424,16 @@ def mla_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,
     return o.reshape(b, s, H * dv) @ p["wo"], new_cache
 
 
-def _mla_absorbed(cfg, p, q_nope, q_pe, rows, kpos, cur_pos, scale, window):
-    """One query per sequence against cached latent rows [B, S, width]
-    (c, k_pe, zero padding): score = [q_nope W_uk^T, q_pe, 0] . row,
-    out = (sum p c) W_uv -> [B,H,dv].
-    Products take the cache's dtype and accumulate in float32; the
-    softmax is float32."""
-    H, r = cfg.num_heads, cfg.kv_lora_rank
-    dn = cfg.qk_nope_head_dim
-    w = p["wkv_b"].reshape(r, H, -1)
-    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w[..., :dn],
+def _mla_latent_query(w, q_nope, q_pe, width):
+    """The absorbed query [q_nope W_uk^T, q_pe, 0] [B, H, width], float32,
+    against cached latent rows (c, k_pe, zero padding); ``w`` is W_kv_b
+    as [r, H, nope + v]."""
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w[..., :q_nope.shape[-1]],
                        preferred_element_type=jnp.float32)
-    pad = rows.shape[-1] - r - q_pe.shape[-1]
-    qc = jnp.concatenate([q_lat, q_pe.astype(jnp.float32),
-                          jnp.zeros(q_lat.shape[:2] + (pad,), jnp.float32)], -1)
-    sc = jnp.einsum("bhc,bkc->bhk", qc.astype(rows.dtype), rows,
-                    preferred_element_type=jnp.float32) * scale
-    dlt = cur_pos[:, None] - kpos
-    ok = (kpos >= 0) & (dlt >= 0)
-    if window is not None:
-        ok &= dlt < window
-    sc = jnp.where(ok[:, None, :], sc, NEG_INF)
-    pr = jax.nn.softmax(sc, axis=-1)
-    o_lat = jnp.einsum("bhk,bkc->bhc", pr.astype(rows.dtype), rows,
-                       preferred_element_type=jnp.float32)[..., :r]
-    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(w.dtype), w[..., dn:],
-                   preferred_element_type=jnp.float32)
-    return o.astype(q_nope.dtype)
+    pad = width - q_lat.shape[-1] - q_pe.shape[-1]
+    return jnp.concatenate([q_lat, q_pe.astype(jnp.float32),
+                            jnp.zeros(q_lat.shape[:2] + (pad,), jnp.float32)],
+                           -1)
 
 
 def self_attn_apply(cfg: ModelConfig, seg: Segment, p: dict, x: jax.Array,

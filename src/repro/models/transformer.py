@@ -388,12 +388,13 @@ def _scan_segment_decode(cfg: ModelConfig, seg: Segment, seg_params, x,
     A paged cache's stacked pools (``<name>_pool`` [count, P, ps, *row]:
     ``k_pool``/``v_pool`` for GQA, ``ckv_pool`` for MLA; blocks.pool_rows)
     ride in the scan carry with the layer index: each layer writes its
-    token into them in place and gathers its pages from them directly
-    (blocks._write_paged_rows / _gather_paged_rows), so no
-    per-layer pool is sliced out and no fresh stacked pool is written
-    back, and a caller that donates the caches gets the pools updated
-    where they lie.  A MoE segment's stacked routed experts are read in
-    place the same way (``_routed_experts``).  Everything else (dense and
+    token into them in place and reads its pages from them directly
+    (blocks._write_paged_rows; blocks._gather_paged_rows for GQA,
+    kernels.paged_latent_attention for MLA), so no per-layer pool is
+    sliced out and no fresh stacked pool is written back, and a caller
+    that donates the caches gets the pools updated where they lie.  A MoE
+    segment's stacked routed experts are read in place the same way
+    (``_routed_experts``).  Everything else (dense and
     ring caches, page tables, lengths, recurrent state, MoE counters)
     scans as ``xs``/``ys``.
     """

@@ -45,7 +45,7 @@ from ..configs.base import ModelConfig
 from ..core.hints import deadline_headroom_s, fair_shares
 from ..kvcache.allocator import OutOfPages, PageAllocator
 from ..models import transformer as T
-from ..models.blocks import pool_rows
+from ..models.blocks import paged_reads_live_pages, pool_rows
 from ..models.moe import MOE_STATS
 from ..telemetry import spans
 
@@ -183,6 +183,9 @@ class ServeEngine:
                        # KV positions the prefill program computed, and how
                        # many of them a shared prefix's pages already held
                        "prefill_tokens": 0, "prefill_tokens_held": 0,
+                       # pool pages the decode steps' attention layers
+                       # read, and the table pages of their active lanes
+                       "decode_pages_read": 0, "decode_table_pages": 0,
                        "per_tenant": {}}
         self._caches = self._init_caches()
         # MoE counters of the prefills (the decode steps' ride in the caches)
@@ -191,6 +194,9 @@ class ServeEngine:
         self._moe_prefill = (jnp.zeros((len(MOE_STATS),), jnp.int32)
                              if self._moe_segs else None)
         self._decode = decode_program(cfg)
+        self._attn_layers = sum(seg.count for seg in self.plan
+                                if seg.has_attention)
+        self._reads_live_pages = paged_reads_live_pages(cfg)
 
         # a named function, so that the trace names the program
         def prefill(params, batch, cache):
@@ -690,8 +696,17 @@ class ServeEngine:
                 else int(req.prompt[-1])
             cur[lane] = self.seq_len[rid]
         self._sync_tables(live)
+        table_pages = len(live) * e.max_pages_per_seq * self._attn_layers
+        pages_read = table_pages
+        if self._reads_live_pages:      # pages up to the token written
+            pages_read = self._attn_layers * sum(
+                self.seq_len[rid] // ps + 1 for rid in live)
+        self._stats["decode_pages_read"] += pages_read
+        self._stats["decode_table_pages"] += table_pages
 
-        with spans.span("umap.engine.decode", lanes=len(live)):
+        with spans.span("umap.engine.decode", lanes=len(live),
+                        decode_pages_read=pages_read,
+                        decode_table_pages=table_pages):
             logits, self._caches = self._decode(
                 self.params, self._caches, jnp.asarray(tokens),
                 jnp.asarray(cur))

@@ -10,6 +10,7 @@ one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
 
+import hashlib
 import importlib.util
 import re
 from functools import partial
@@ -220,6 +221,76 @@ def test_engine_decode_reads_routed_experts_in_place_on_v5e(one_chip,
         loop_views = re.findall(r"\n\s+%\S+ = bf16" + re.escape(view)
                                 + r"\S* bitcast\(", hlo.split("\nENTRY")[0])
         assert loop_views, f"no {view} view of the stacked experts in the loop"
+
+
+def _cell_decode(arch, engine, dtype, sharding):
+    """The engine's decode program for ``arch`` at a serve cell's engine
+    shape (lanes, page size, pages, table width) and weight dtype, lowered
+    for one chip: (lowered program, caches' shapes)."""
+    cfg = get_config(arch).replace(head_pad_multiple=1)
+    if arch == "deepseek-v2-lite":       # the benchmark's one-chip depth
+        cfg = cfg.replace(num_layers=5)
+    b, ps, pages, maxp = engine
+    ecfg = EngineConfig(max_batch=b, page_size=ps, num_pages=pages,
+                        max_pages_per_seq=maxp)
+    params = jax.eval_shape(partial(T.init_params, cfg, dtype=dtype),
+                            jax.random.key(0))
+    ns = SimpleNamespace(cfg=cfg, ecfg=ecfg, plan=cfg.decoder_plan())
+    caches = jax.eval_shape(partial(ServeEngine._init_caches, ns))
+    lowered = decode_program(cfg).lower(
+        _on(params, sharding), _on(caches, sharding),
+        _sds((b,), jnp.int32, sharding), _sds((b,), jnp.int32, sharding))
+    return lowered, caches
+
+
+def test_mla_decode_reads_live_latent_pages_in_place_on_v5e(one_chip,
+                                                             monkeypatch):
+    """DeepSeek-V2-Lite's decode program at ``longctx_decode``'s engine
+    shape, traced as for the chip, compiles (the live-pages kernel within
+    the scoped fast memory, its page table in scalar memory) and reads
+    each segment's latent pool through the kernel where the pool lies: no
+    instruction gathers the table's pages ([B x maxp, ps, 640]) and none
+    copies a pool; a pool-shaped array is only the token's write."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, ps, pages, maxp = engine = (32, 16, 28705, 897)
+    lowered, caches = _cell_decode("deepseek-v2-lite", engine, jnp.bfloat16,
+                                   one_chip)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    kernels = re.findall(r"%paged_latent_attention\S* = f32\[(\d+),(\d+),"
+                         r"(\d+)\]\S* custom-call\(", hlo)
+    assert kernels and set(kernels) == {(str(b), "16", "640")}
+    for shape in [(b * maxp, ps, 640), (b, maxp, ps, 640)]:
+        assert not _pool_instructions(hlo, (1,) + shape), shape
+    pools = [c["ckv_pool"] for c in caches]
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    for pool in pools:
+        found = _pool_instructions(hlo, pool.shape)
+        assert found and all(f[2].endswith("/scatter") for f in found), found
+
+
+# sha256 of the decode program's StableHLO where the latent reader came
+# in: GQA decode runs none of it.  A change to GQA decode changes these.
+GQA_DECODE_TEXT = {
+    "qwen2-1.5b": ((32, 16, 8225, 257), jnp.bfloat16,
+                   "e5803203e9be98e920ef0b10ca4f01dd"
+                   "16e184206294aa5e7ae9977be2037e77"),
+    "smollm-135m": ((32, 16, 1313, 41), jnp.float32,
+                    "543ac4961fdb5f4821d6e5a155de3106"
+                    "921c21293b73acf99391f4dc24d8d306"),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(GQA_DECODE_TEXT))
+def test_gqa_decode_program_text_is_pinned(one_chip, monkeypatch, arch):
+    """``decode_long``'s and ``chat_short``'s decode programs (Qwen2-1.5B,
+    SmolLM-135M at their cells' engine shapes, traced as for the chip)
+    lower to the same text as before the latent reader came in."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine, dtype, digest = GQA_DECODE_TEXT[arch]
+    lowered, _ = _cell_decode(arch, engine, dtype, one_chip)
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == digest
 
 
 def test_engine_prefill_compiles_for_v5e(one_chip, smollm):
